@@ -57,11 +57,8 @@ def load_or_init_extractor(tile: int):
 
 
 def cost_analysis(fn, *args):
-    """(flops, bytes accessed) of a jitted fn per XLA ``cost_analysis``
-    (papers over the list-vs-dict return across jax versions)."""
+    """(flops, bytes accessed) of a jitted fn per XLA ``cost_analysis``."""
     c = fn.lower(*args).compile().cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
     return (float(c.get("flops", 0.0)),
             float(c.get("bytes accessed", 0.0)))
 

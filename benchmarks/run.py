@@ -47,6 +47,8 @@ def main() -> int:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import init_compile_cache
+    init_compile_cache()
     print("name,us_per_call,derived", flush=True)
     failures = []
     for mod_name, desc in MODULES:

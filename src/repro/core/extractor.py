@@ -128,6 +128,14 @@ def quantize_rows_int8(x2d):
     return q, s
 
 
+def mxu_precision(dtype):
+    """Dot precision for a compute dtype: fp32 operands run at full
+    fp32 precision (``HIGHEST``) everywhere, so the fp32 rung means
+    fp32 on the TPU's MXU too; bf16 operands keep the default single
+    pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
 def _shifts3x3(x):
     """The nine 3x3-tap shifted views of x (b, h, w, c), zero padding,
     [ky, kx] order — the implicit im2col a SAME 3x3 conv reads."""
@@ -143,8 +151,7 @@ def tap_dot(xs2d, w2d, tap, cin, scale=None):
 
     THE per-tap primitive every decode path shares (the unfused graph,
     the flat Pallas kernel, and the blocked kernel all accumulate these
-    in the same static tap order, which the bit-identity contract
-    depends on).  fp32/bf16 weights: cast input, MXU dot, fp32
+    in the same static tap order).  fp32/bf16 weights: cast input, MXU dot, fp32
     accumulation.  int8 weights (``scale`` = the per-output-channel
     dequant scale, column-sliced the same way as ``w2d`` when the
     caller channel-tiles): dynamic per-row activation quantization,
@@ -157,6 +164,7 @@ def tap_dot(xs2d, w2d, tap, cin, scale=None):
                                 preferred_element_type=jnp.int32)
         return y.astype(jnp.float32) * s * scale[None, :]
     return jnp.dot(xs2d.astype(w2d.dtype), wt,
+                   precision=mxu_precision(w2d.dtype),
                    preferred_element_type=jnp.float32)
 
 
@@ -168,8 +176,7 @@ def conv3x3_mm(x, w2d, scale=None):
     matmul, so the live working set stays activation-sized (the
     full-image sequential path and training also run this body).  Tap
     order is static, every tap dot keeps M = b*h*w, and the nine
-    partial sums add elementwise — all batch-stable, which the
-    fused/unfused bit-identity contract depends on.  ``scale`` carries
+    partial sums add elementwise.  ``scale`` carries
     the int8 rung's per-channel dequant scales (see :func:`tap_dot`)."""
     b, h, w, c = x.shape
     acc = None
@@ -226,10 +233,11 @@ def pack_params(params, dtype="fp32"):
     }
     if "corr" in params:
         n, t = params["corr"].shape[0], params["corr"].shape[1]
-        # (n, t, t, 3) -> (t*t, n, 3): pixel-major so the correlation
-        # reduces over (pixel, channel) with batch-stable shapes
-        pk["corr"] = params["corr"].transpose(1, 2, 0, 3).reshape(
-            t * t, n, 3).astype(hdt)
+        # (n, t, t, 3) -> (t*t*3, n), row p*3 + c: the correlation is
+        # then one (b, t*t*3) x (t*t*3, n) dot over the flattened
+        # channels-last highpass tiles
+        pk["corr"] = params["corr"].transpose(1, 2, 3, 0).reshape(
+            t * t * 3, n).astype(hdt)
         pk["corr_scale"] = params["corr_scale"].astype(jnp.float32)
     return pk
 
@@ -262,43 +270,24 @@ def unpack_params(packed):
                  "b": packed["head"]["b"]},
     }
     if "corr" in packed:
-        t2, n, _ = packed["corr"].shape
-        t = int(round(t2 ** 0.5))
+        t3, n = packed["corr"].shape
+        t = int(round((t3 // 3) ** 0.5))
         p["corr"] = packed["corr"].astype(jnp.float32).reshape(
-            t, t, n, 3).transpose(2, 0, 1, 3)
+            t, t, 3, n).transpose(3, 0, 1, 2)
         p["corr_scale"] = packed["corr_scale"]
     return p
 
 
-def extractor_forward_packed_embed(packed, tiles):
-    """:func:`extractor_forward_packed` that additionally returns the
-    GAP vector ``g`` — the to_bits global-average-pooled features the
-    head consumes.  ``g`` is the serving tier's near-duplicate
-    embedding (``serving.cache.EmbeddingCache``): it already exists on
-    the logits path, so exposing it costs one extra kernel output and
-    zero extra arithmetic, and the logits are computed by the exact
-    same ops either way (bitwise identical to the embed-free call).
+def conv_head_packed(packed, tiles):
+    """The conv path of the packed forward: conv blocks, to_bits, GAP
+    and head -> (head logits, GAP vector ``g``), both (b, n_bits) f32.
 
-    This is THE shared body: ``extractor_forward`` (the unfused XLA
-    graph) and the Pallas kernel grid step (block shape (1, l, l, 3))
-    both run it verbatim, so the fused/unfused bit-identity contract
-    cannot silently drift — and every op is *batch-stable* (a size-b
-    batch computes row i exactly as a size-1 batch would):
-
-    * conv matmuls keep M = b*l*l (slice-stable GEMM shapes), with the
-      nine taps accumulated in static order (``conv3x3_mm``);
-    * GAP is a (1, 2)-axis mean with the batch dim leading;
-    * head and correlation contract via broadcast-multiply + reduce
-      instead of M=b GEMV/GEMM dots, whose K-accumulation order is
-      batch-dependent on some backends (they are a negligible slice of
-      decode FLOPs).
-
-    Matmul inputs are cast to the packed compute dtype; accumulation
-    (``preferred_element_type``), the highpass (elementwise VPU work)
-    and the epilogue stay fp32.  int8 packs route their conv matmuls
-    through the quantized ``tap_dot`` path (head/corr read the pack's
-    fp32 head dtype, so the fp32/bf16 graphs are untouched).
-    """
+    The Pallas decode kernel runs this body per grid step; the
+    unfused graph runs it over the whole batch.  Matmul inputs are
+    cast to the packed compute dtype; accumulation
+    (``preferred_element_type``) and the epilogue stay fp32.  int8
+    packs route their conv matmuls through the quantized ``tap_dot``
+    path (the head reads the pack's fp32 head dtype)."""
     b, l = tiles.shape[0], tiles.shape[1]
     cdt = packed["head"]["w"].dtype
     x = tiles
@@ -310,15 +299,45 @@ def extractor_forward_packed_embed(packed, tiles):
                    packed["to_bits"].get("scale"))
     y = y.reshape(b, l, l, -1) + packed["to_bits"]["b"]
     g = y.mean(axis=(1, 2))  # GAP
-    logits = (g.astype(cdt)[:, :, None] * packed["head"]["w"][None]
-              ).astype(jnp.float32).sum(axis=1) + packed["head"]["b"]
-    if "corr" in packed and packed["corr"].shape[0] == l * l:
-        # correlation path only at the bank's native tile size (the conv
-        # path alone handles other sizes, e.g. full-image baseline mode)
-        hp = (tiles - _box3x3(tiles)).reshape(b, l * l, 1, 3)
-        corr = (hp.astype(cdt) * packed["corr"][None]
-                ).astype(jnp.float32).sum(axis=(1, 3))
-        logits = logits + corr * packed["corr_scale"]
+    logits = jnp.dot(g.astype(cdt), packed["head"]["w"],
+                     precision=mxu_precision(cdt),
+                     preferred_element_type=jnp.float32)
+    return logits + packed["head"]["b"], g
+
+
+def correlate_packed(packed, tiles):
+    """The spread-spectrum correlation term (b, n_bits) f32, or None
+    when the pack has no bank or the bank's tile size differs from the
+    tiles' (the conv path alone then decodes, e.g. full-image baseline
+    mode).  highpass = tiles minus their 3x3 box blur, flattened
+    channels-last and correlated with the whole bank in one dot."""
+    b, l = tiles.shape[0], tiles.shape[1]
+    if "corr" not in packed or packed["corr"].shape[0] != l * l * 3:
+        return None
+    cdt = packed["corr"].dtype
+    hp = (tiles - _box3x3(tiles)).reshape(b, l * l * 3)
+    corr = jnp.dot(hp.astype(cdt), packed["corr"],
+                   precision=mxu_precision(cdt),
+                   preferred_element_type=jnp.float32)
+    return corr * packed["corr_scale"]
+
+
+def extractor_forward_packed_embed(packed, tiles):
+    """:func:`extractor_forward_packed` that additionally returns the
+    GAP vector ``g`` — the to_bits global-average-pooled features the
+    head consumes.  ``g`` is the serving tier's near-duplicate
+    embedding (``serving.cache.EmbeddingCache``): it already exists on
+    the logits path, so exposing it costs no extra arithmetic.
+
+    The conv path (:func:`conv_head_packed`) is the body the Pallas
+    decode kernel runs per grid step; the correlation term
+    (:func:`correlate_packed`) is one batched dot outside the kernel.
+    The kernel path and this unfused graph add the two the same way.
+    """
+    logits, g = conv_head_packed(packed, tiles)
+    corr = correlate_packed(packed, tiles)
+    if corr is not None:
+        logits = logits + corr
     return logits, g
 
 
@@ -333,17 +352,16 @@ def extractor_forward(params, tiles):
     """tiles (b, l, l, 3) in [-1, 1] -> bit logits (b, n_bits).
 
     Same math as the original conv formulation (semantic oracle:
-    ``kernels.ref.fused_extractor_ref``), expressed through the shared
-    matmul body so the fused fp32 kernel is bit-identical to this
-    unfused path by construction.  Packing inside jit is free (reshapes
-    and casts constant-fold)."""
+    ``kernels.ref.fused_extractor_ref``), expressed through the packed
+    matmul body the fused kernel also computes.  Packing inside jit is
+    free (reshapes and casts constant-fold)."""
     return extractor_forward_packed(pack_params(params), tiles)
 
 
 def extractor_forward_embed(params, tiles):
     """Unfused forward returning (logits, gap_embedding) — the
     embed-emitting decode for pipelines running without the fused
-    kernel (``fused_decode=False``).  Logits are bitwise identical to
+    kernel (``fused_decode=False``).  Logits are those of
     :func:`extractor_forward` (same body, same op order)."""
     return extractor_forward_packed_embed(pack_params(params), tiles)
 

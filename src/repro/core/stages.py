@@ -31,6 +31,7 @@ batched together.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -51,13 +52,16 @@ STAGE_NAMES = ("ingest", "decode", "rs")
 _PALLAS_RS_CODE = (4, 15, 12)  # (m, n, k)
 
 
+@functools.lru_cache(maxsize=None)
 def make_device_rs(code: RSCode) -> Callable:
     """The on-device batched RS engine: the Pallas Berlekamp-Welch
     kernel for the code it is specialised for, ``jax_rs`` otherwise.
     Jit-able and safe to inline into a larger jitted graph — every
     engine (fused fast path, lane executor, sharded run_batch, online
     server) must use the same decoder so failure tie-breaking never
-    diverges."""
+    diverges.  One jitted decoder per code and process, so every
+    registry (each server, replica or pipeline) reuses its compiled
+    programs instead of compiling the kernel again."""
     if (code.m, code.n, code.k) == _PALLAS_RS_CODE:
         from repro.kernels import ops as kops
 
@@ -172,6 +176,7 @@ class StageRegistry:
         self.fused_decode = cfg.fused_decode and cfg.mode == "qrmark"
         self._rs_pool: Optional[RSCorrectionPool] = None
         self._device_rs = None
+        self._sharded: Dict[Any, Callable] = {}   # mesh -> program
         self._pool_seq = 0            # RS-pool job id counter
         self._pool_lock = threading.Lock()
         self._build()
@@ -206,8 +211,7 @@ class StageRegistry:
         # decode-stage extractor, one fn for every engine: the fused
         # Pallas kernel on pre-packed params (qrmark; pack once per
         # registry build, dtype = the precision policy) or the unfused
-        # extractor_forward graph (bit-identical to the fp32 kernel —
-        # they share extractor_forward_packed)
+        # extractor_forward graph (the same packed math as one XLA graph)
         if self.fused_decode:
             from repro.kernels import autotune as autotune_lib
             from repro.kernels import ops as kops
@@ -216,8 +220,8 @@ class StageRegistry:
             # kernel schedule, resolved once per registry build: "flat"
             # -> None (the flat kernel), "auto" -> the autotune cache
             # (flat fallback with a printed hint on a miss), or an
-            # explicit "bb<N>-ct<N>[-db]" point.  fp32 output is bitwise
-            # schedule-independent, so this is purely a throughput knob.
+            # explicit "bb<N>-ct<N>[-db]" point.  Only flat compiles for
+            # TPU; the others are refused there rather than interpreted.
             self.decode_schedule = autotune_lib.resolve_schedule(
                 getattr(cfg, "decode_schedule", "flat"),
                 dtype=cfg.decode_dtype, tile=cfg.tile,
@@ -226,6 +230,14 @@ class StageRegistry:
                 n_bits=self.params["head"]["b"].shape[0],
                 cache_path=getattr(cfg, "autotune_cache", ""))
             sched = self.decode_schedule
+            if jax.default_backend() == "tpu" and (
+                    sched is not None or cfg.decode_dtype == "int8"):
+                raise ValueError(
+                    f"decode_schedule={cfg.decode_schedule!r} with "
+                    f"decode_dtype={cfg.decode_dtype!r} does not compile "
+                    f"for TPU: the blocked decode kernel and the int8 "
+                    f"rung run in interpret mode on the CPU only; use "
+                    f"decode_schedule='flat' with fp32 or bf16")
 
             def extract(tiles):
                 return kops.fused_extractor(tiles, self.packed_params,
@@ -372,6 +384,35 @@ class StageRegistry:
             self.fused_keyed = jax.jit(fused_keyed, donate_argnums=donate)
         else:
             self.fused_keyed = None
+
+    def sharded_round(self, mesh) -> Callable:
+        """(raw, keys) sharded on ``mesh``'s "data" axis -> logits, plus
+        msg, ok, ncorr when RS runs on device, all sharded like the
+        batch: one program in which each device runs ingest, decode and
+        RS on its own shard (``shard_map``).  Pallas kernels cannot be
+        partitioned automatically, and every stage is per-image, so the
+        program has no collective.  Built once per mesh."""
+        fn = self._sharded.get(mesh)
+        if fn is None:
+            spec = jax.sharding.PartitionSpec("data")
+            device_rs = self._device_rs
+
+            def per_shard(raw, keys):
+                logits = self.decode_keyed(self.ingest_keyed(raw, keys),
+                                           keys)
+                if device_rs is None:
+                    return (logits,)
+                out = device_rs(self.bits(logits))
+                return (logits, out["message_bits"], out["ok"],
+                        out["n_corrected"])
+
+            # check_vma off: the kernels' out_shape structs carry no
+            # mesh-axis annotation
+            fn = jax.jit(jax.shard_map(per_shard, mesh=mesh,
+                                       in_specs=(spec, spec),
+                                       out_specs=spec, check_vma=False))
+            self._sharded[mesh] = fn
+        return fn
 
     # -- RS correction ---------------------------------------------------
     def _rs_host(self, bits: np.ndarray):

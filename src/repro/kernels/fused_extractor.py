@@ -1,82 +1,97 @@
-"""Pallas TPU kernel: the fused extractor decode stage.
+"""Pallas TPU kernels: the fused extractor decode stage.
 
-After PR 2's tile-first ingest, decode — ``extractor_forward``'s 7-block
-conv stack, GAP + head, and the spread-spectrum correlation bank — is
-the last hot-path stage still running as an unfused XLA graph at full
-precision: every conv block round-trips its (l, l, C) activations
-through HBM, and QRMark §5.2 identifies exactly this stage as the
-GPU-intensive bottleneck that gets extra streams.  Two kernels share
-one math contract:
+Decode — the extractor's conv stack, GAP + head — runs as one
+``pallas_call`` per tile batch, so conv activations never round-trip
+HBM (QRMark §5.2 names this stage the accelerator-bound one).  The
+spread-spectrum correlation bank is one batched MXU dot after the
+kernel (``extractor.correlate_packed``), and the two are added the way
+the unfused graph adds them.  Two kernels compute the same math:
 
-``fused_extractor`` (the *flat* schedule) runs the whole forward in one
-``pallas_call`` with grid=(b,), one image per step, by calling the
-shared ``extractor_forward_packed`` body verbatim inside the step.
+``fused_extractor`` (the *flat* schedule, the only one that compiles
+for TPU) — grid=(b,), one image per step.  The activation is a
+flattened (l*l, C) array with channels on lanes, held in VMEM between
+zero halo rows; a 3x3 tap is a window of it (details in the function's
+docstring).  The conv runs in row chunks under ``fori_loop`` and blocks
+1..D-1 run as one loop over stacked weights, so the compiled kernel
+holds one layer body.
 
-``fused_extractor_blocked`` (the *blocked* schedule, this PR) re-blocks
-that step for throughput while keeping the accumulation order — and
-therefore fp32 bitwise output — exactly the same:
+``fused_extractor_blocked`` (the *blocked* schedule) — grid=(b //
+batch_block,) over (bb, l, l, 3) blocks:
 
-* grid=(b // batch_block,): each step owns a (bb, l, l, 3) image block;
 * a padded-activation VMEM scratch (bb, l+2, l+2, C) holds every
-  inter-layer activation with its halo in place, so layers 1..D read
-  their nine tap-shifted views as scratch slices instead of re-running
-  a ``jnp.pad`` copy per layer (the flat kernel pays that copy D+1
-  times per image);
+  inter-layer activation with its halo in place, so layers read their
+  nine tap-shifted views as scratch slices;
 * a (bb*l*l, C) accumulator scratch collects the conv output one
-  channel tile at a time: the weight's output columns are visited in
-  [j0, j0+ct) slices, nine N-restricted tap dots per slice.  N-slicing
-  a dot never reorders its K-accumulation, so any channel_tile is
-  bit-identical to the full-width dot (verified property; contrast
-  K-splitting, which is not).  A *cross-step* channel axis is
-  impossible here — channel_norm couples all C channels of a layer and
-  layer i+1 reads all of layer i — so the tile is an in-body loop that
-  bounds the live weight slice, not a grid dimension;
-* the bias + channel-norm + ReLU epilogue runs directly on the (M, C)
-  GEMM layout ("flat-norm") and the result lands in the scratch
-  interior; channel_norm reduces over the channel axis only, so
-  skipping the (bb, l, l, C) round-trip is bitwise free and removes
-  two reshape copies per layer;
-* GAP + head + correlation ride in the same step, written straight to
-  the (b, n_bits) logits output.
+  channel tile at a time (N-restricted tap dots); channel_norm couples
+  all C channels of a layer, so the tile is an in-body loop, not a grid
+  dimension;
+* the bias + channel-norm + ReLU epilogue runs on the (M, C) GEMM
+  layout, then GAP + head end the step.
+
+It keeps channels-last 4-D blocks and does not compile for TPU;
+``StageRegistry`` refuses it there.
 
 The precision ladder is carried by the packed params, not the kernel:
-fp32 packs are bit-identical to the unfused path on either schedule
-(oracle parity by construction), bf16 packs run bf16-input MXU dots
-with fp32 accumulation, and int8 packs (``pack_params(..., "int8")``)
-run per-channel-scaled int8 weight x dynamically per-row-quantized
-activation dots with int32 accumulation and fp32 dequantize — all three
-share the per-tap ``tap_dot`` primitive, so RS error correction sees
-the same decode semantics at every rung.  (One caveat: int8 is bitwise
-schedule-independent only at full channel width — with channel_tile <
-C the dequant multiply-add chain may fuse differently per tile width,
-leaving ulp-level float noise that the decision layer never sees;
-fp32/bf16 are bitwise at every tile.)
-
-Bit-identity depends on every op in the shared body being batch-stable
-(see ``extractor_forward_packed``).  interpret=True executes on CPU
-(this container); interpret=False is the TPU target, where
-``double_buffer`` requests parallel grid-dimension semantics so
-consecutive image blocks pipeline their HBM fetches.
+fp32 packs run full-precision dots (``Precision.HIGHEST``), bf16 packs
+bf16-input MXU dots with fp32 accumulation, int8 packs
+(``pack_params(..., "int8")``) per-channel-scaled int8 weight x per-row
+quantized activation dots with int32 accumulation and fp32 dequantize —
+all through the per-tap ``tap_dot`` primitive.  Results of the two
+kernels and of the unfused graph agree under the cross-program contract
+(``docs/api.md``).  interpret=True executes on the CPU; interpret=False
+compiles for the TPU.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU scratch/compiler params; present in this JAX, guarded anyway
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - non-TPU builds
-    pltpu = None
+from repro.core.extractor import (channel_norm, conv_head_packed,
+                                  correlate_packed, mxu_precision, tap_dot)
 
-from repro.core.extractor import (channel_norm,
-                                  extractor_forward_packed_embed, tap_dot)
+
+# Pixels per row chunk of the flat kernel's conv loop: each loop body
+# holds (CHUNK_PIXELS, C) tap views, so the kernel's code and its live
+# values stay small whatever the tile size.
+CHUNK_PIXELS = 512
+
+def _split_corr(packed):
+    """The packed params without the correlation bank: the kernels run
+    the conv path only; the bank's one dot runs outside them."""
+    return {k: v for k, v in packed.items()
+            if k not in ("corr", "corr_scale")}
+
+
+def _add_corr(out, packed, tiles, with_embed):
+    """Add the correlation term (``correlate_packed``, one batched dot
+    in XLA) to the kernel's head logits."""
+    logits, g = (out if with_embed else (out, None))
+    corr = correlate_packed(packed, tiles)
+    if corr is not None:
+        logits = logits + corr
+    return (logits, g) if with_embed else logits
 
 
 def _full_spec(shape):
     """BlockSpec broadcasting one whole (weight) array to every step."""
     nd = len(shape)
     return pl.BlockSpec(shape, lambda i, _nd=nd: (0,) * _nd)
+
+
+def _stack_mid_blocks(packed):
+    """Kernel params: block 0 (cin=3) apart, blocks 1..D-1 (all C -> C)
+    stacked on a leading axis so the kernel loops over them, plus
+    to_bits and head.  The correlation bank stays out."""
+    blocks = packed["blocks"]
+    kp = {"first": blocks[0], "to_bits": packed["to_bits"],
+          "head": packed["head"]}
+    if len(blocks) > 1:
+        kp["mid"] = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks[1:])
+    return kp
 
 
 def fused_extractor(tiles, packed, *, interpret: bool = True,
@@ -89,36 +104,126 @@ def fused_extractor(tiles, packed, *, interpret: bool = True,
     fp32 / bf16 / int8 compute path.  Not jitted here: callers jit
     around it.
 
+    The kernel computes the conv path of ``conv_head_packed`` (same
+    per-tap dots, same tap order, same epilogue) on a flattened
+    (l*l, C) activation: a SAME 3x3 tap is a window of a VMEM scratch
+    that holds the activation between zero halo rows, offset by
+    ``(dy-1)*l + (dx-1)`` rows, with the pixels that wrapped across a
+    row edge masked to zero.  Every value stays 2-D with channels on
+    lanes, and blocks 1..D-1 run as one loop over stacked weights, so
+    the kernel compiles in one layer body's time instead of D.  The
+    correlation bank's dot runs after the kernel in XLA
+    (``correlate_packed``).
+
+    Each step writes a (1, n_bits) block of a (b, 1, n_bits) output:
+    the block's last two dims equal the array's, which the TPU compiler
+    requires of a block narrower than (8, 128).
+
     ``with_embed=True`` returns ``(logits, embed)`` where ``embed`` is
     the (b, n_bits) f32 GAP vector the head consumes — an intermediate
-    the kernel already computes, written to a second output block.  The
-    logits path is untouched op-for-op, so fp32 logits are bitwise
-    identical with or without the extra output.
+    the kernel already computes, written to a second output block.
     """
     b, l = tiles.shape[0], tiles.shape[1]
+    L = l * l
+    pad = -(-(l + 8) // 8) * 8        # zero halo rows, sublane-aligned
+    rows = math.gcd(l, max(1, CHUNK_PIXELS // l))
+    CH = rows * l                     # pixels per chunk: whole rows
+    S = L + 2 * pad                   # rows of one padded activation
+    n_chunks = L // CH
     n_bits = packed["head"]["b"].shape[0]
-    leaves, treedef = jax.tree.flatten(packed)
+    C = packed["blocks"][0]["w"].shape[-1]
+    kp = _stack_mid_blocks(packed)
+    n_mid = len(packed["blocks"]) - 1
+    leaves, treedef = jax.tree.flatten(kp)
+    n_par = len(leaves)
     n_out = 2 if with_embed else 1
 
     def kernel(img_ref, *refs):
-        param_refs, out_refs = refs[:-n_out], refs[-n_out:]
-        pk = jax.tree.unflatten(treedef, [r[...] for r in param_refs])
-        logits, g = extractor_forward_packed_embed(pk, img_ref[...])
-        out_refs[0][...] = logits
+        pk = jax.tree.unflatten(treedef, refs[:n_par])
+        out_refs = refs[n_par: n_par + n_out]
+        x0_ref, buf_ref = refs[-2:]
+        # chunks are whole image rows, so every chunk has the same
+        # column pattern
+        col = jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (CH, 1), 0), l)
+        edge_ok = {0: col != 0, 2: col != l - 1}
+
+        def conv_chunk(src, off, r0, w, scale, cin):
+            """Rows [r0, r0+CH) of the SAME 3x3 conv of the activation
+            held in rows [off, off + S) of ``src`` between zero halo
+            rows: per kernel row dy one aligned window load, its three
+            dx taps static sublane shifts of it, pixels wrapped across
+            a row edge zeroed."""
+            acc = None
+            for dy in range(3):
+                base = pl.multiple_of(
+                    off + pad + r0 + (dy - 1) * l - 8, 8)
+                win = src[pl.ds(base, CH + 16), :]
+                for dx in range(3):
+                    xs = win[7 + dx: 7 + dx + CH]
+                    if dx != 1:
+                        xs = jnp.where(edge_ok[dx], xs, 0.0)
+                    y = tap_dot(xs, w, 3 * dy + dx, cin, scale)
+                    acc = y if acc is None else acc + y
+            return acc
+
+        def layer(src, src_off, dst_off, entry, cin):
+            def chunk(j, carry):
+                r0 = pl.multiple_of(j * CH, 8)
+                y = conv_chunk(src, src_off, r0, entry["w"],
+                               entry.get("scale"), cin)
+                dst = pl.multiple_of(dst_off + pad + r0, 8)
+                buf_ref[pl.ds(dst, CH), :] = jax.nn.relu(
+                    channel_norm(y + entry["b"]))
+                return carry
+            jax.lax.fori_loop(0, n_chunks, chunk, 0)
+
+        x0_ref[...] = jnp.zeros_like(x0_ref)
+        buf_ref[...] = jnp.zeros_like(buf_ref)
+        x0_ref[pad: pad + L, :] = img_ref[...].reshape(L, 3)
+        # buf_ref holds two activations (rows [0, S) and [S, 2S));
+        # layer i reads one and writes the other
+        layer(x0_ref, 0, 0, {k: r[...] for k, r in pk["first"].items()},
+              3)
+        if n_mid:
+            def mid(i, carry):
+                layer(buf_ref, (i % 2) * S, ((i + 1) % 2) * S,
+                      {k: r[i] for k, r in pk["mid"].items()}, C)
+                return carry
+            jax.lax.fori_loop(0, n_mid, mid, 0)
+        tb = {k: r[...] for k, r in pk["to_bits"].items()}
+
+        def gap_chunk(j, acc):
+            y = conv_chunk(buf_ref, (n_mid % 2) * S,
+                           pl.multiple_of(j * CH, 8), tb["w"],
+                           tb.get("scale"), C) + tb["b"]
+            return acc + jnp.sum(y, axis=0, keepdims=True)
+        g = jax.lax.fori_loop(0, n_chunks, gap_chunk,
+                              jnp.zeros((1, n_bits), jnp.float32)) / L
+        cdt = pk["head"]["w"].dtype
+        logits = jnp.dot(g.astype(cdt), pk["head"]["w"][...],
+                         precision=mxu_precision(cdt),
+                         preferred_element_type=jnp.float32)
+        out_refs[0][...] = logits + pk["head"]["b"][...]
         if with_embed:
             out_refs[1][...] = g
 
-    out_spec = pl.BlockSpec((1, n_bits), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((b, n_bits), jnp.float32)
-    return pl.pallas_call(
+    out_spec = pl.BlockSpec((None, 1, n_bits), lambda i: (i, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((b, 1, n_bits), jnp.float32)
+    out = pl.pallas_call(
         kernel,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, l, l, 3), lambda i: (i, 0, 0, 0))] +
+        in_specs=[pl.BlockSpec((None, l, l, 3), lambda i: (i, 0, 0, 0))] +
                  [_full_spec(x.shape) for x in leaves],
-        out_specs=[out_spec] * n_out if with_embed else out_spec,
-        out_shape=[out_shape] * n_out if with_embed else out_shape,
+        out_specs=[out_spec] * n_out,
+        out_shape=[out_shape] * n_out,
+        scratch_shapes=[pltpu.VMEM((L + 2 * pad, 3), jnp.float32),
+                        pltpu.VMEM((2 * S, C), jnp.float32)],
         interpret=interpret,
     )(tiles, *leaves)
+    out = tuple(o.reshape(b, n_bits) for o in out)
+    return _add_corr(out if with_embed else out[0], packed, tiles,
+                     with_embed)
 
 
 def _taps_fold(read_tap, entry, cin, j0, nj):
@@ -138,10 +243,6 @@ def _taps_fold(read_tap, entry, cin, j0, nj):
 
 def _scratch_shapes(bb, l, C):
     """Padded-activation + channel-tile accumulator scratch in VMEM."""
-    if pltpu is None:  # pragma: no cover - jax builds without pallas-tpu
-        raise NotImplementedError(
-            "blocked decode schedule needs pallas TPU scratch shapes; "
-            "use the flat schedule (decode_schedule='flat') instead")
     return [pltpu.VMEM((bb, l + 2, l + 2, C), jnp.float32),
             pltpu.VMEM((bb * l * l, C), jnp.float32)]
 
@@ -182,7 +283,7 @@ def fused_extractor_blocked(tiles, packed, *, batch_block: int = 1,
             return out[0][:b], out[1][:b]
         return out[:b]
 
-    leaves, treedef = jax.tree.flatten(packed)
+    leaves, treedef = jax.tree.flatten(_split_corr(packed))
     M = bb * l * l
     n_out = 2 if with_embed else 1
 
@@ -227,34 +328,20 @@ def fused_extractor_blocked(tiles, packed, *, batch_block: int = 1,
         if with_embed:
             out_refs[1][...] = g
         cdt = pk["head"]["w"].dtype
-        logits = (g.astype(cdt)[:, :, None] * pk["head"]["w"][None]
-                  ).astype(jnp.float32).sum(axis=1) + pk["head"]["b"]
-        if "corr" in pk and pk["corr"].shape[0] == l * l:
-            # highpass = img - box blur, the blur as the same nine-tap
-            # sum _box3x3 runs (reusing the layer-0 padded block)
-            accb = None
-            for tap in range(9):
-                dy, dx = divmod(tap, 3)
-                xs = jax.lax.slice(x4, (0, dy, dx, 0),
-                                   (bb, dy + l, dx + l, 3))
-                accb = xs if accb is None else accb + xs
-            hp = (tiles_blk - accb * (1.0 / 9.0)).reshape(bb, l * l, 1, 3)
-            corr = (hp.astype(cdt) * pk["corr"][None]
-                    ).astype(jnp.float32).sum(axis=(1, 3))
-            logits = logits + corr * pk["corr_scale"]
-        out_ref[...] = logits
+        logits = jnp.dot(g.astype(cdt), pk["head"]["w"],
+                         precision=mxu_precision(cdt),
+                         preferred_element_type=jnp.float32)
+        out_ref[...] = logits + pk["head"]["b"]
 
     kwargs = {}
-    if double_buffer and not interpret and pltpu is not None:
-        try:  # pipeline consecutive image blocks on TPU
-            kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel",))
-        except (AttributeError, TypeError):  # pragma: no cover
-            pass
+    if double_buffer and not interpret:
+        # pipeline consecutive image blocks on TPU
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",))
 
     out_spec = pl.BlockSpec((bb, n_bits), lambda i: (i, 0))
     out_shape = jax.ShapeDtypeStruct((b, n_bits), jnp.float32)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(b // bb,),
         in_specs=[pl.BlockSpec((bb, l, l, 3), lambda i: (i, 0, 0, 0))] +
@@ -265,3 +352,5 @@ def fused_extractor_blocked(tiles, packed, *, batch_block: int = 1,
         interpret=interpret,
         **kwargs,
     )(tiles, *leaves)
+    return _add_corr(tuple(out) if with_embed else out, packed, tiles,
+                     with_embed)

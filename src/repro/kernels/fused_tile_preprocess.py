@@ -12,8 +12,9 @@ is two interpolation matmuls per channel,
 
 the (y, x) tile of the output only needs rows [y, y+l) of ``Ry`` and
 columns [x, x+l) of ``Rx`` — output row i depends on nothing but row i of
-``Ry``, so slicing the interpolation matrices *before* the matmuls yields
-bit-identical values to slicing the full preprocessed image after them,
+``Ry``, so slicing the interpolation matrices *before* the matmuls yields the
+same values as slicing the full preprocessed image after them (up to
+float reassociation by the compiler),
 while shrinking the per-image FLOPs from
 
     3 * (crop*H*W + crop*W*crop)   to   3 * (l*H*W + l*W*l).
@@ -22,8 +23,9 @@ Per-image tile offsets (already derived from per-image fold_in keys by
 ``tiling.per_image_offsets``, so they are available *before* ingest) are
 applied as a vmapped ``dynamic_slice`` over the shared (crop, H)/(W, crop)
 matrices on the way into the kernel; the kernel itself is two small MXU
-matmuls per channel per grid step and writes the (b, l, l, 3) decode
-input directly — the full preprocessed image is never materialised.
+matmuls per channel per grid step on a planar (3, H, W) image block
+(layout notes in ``fused_preprocess.py``) and yields the (b, l, l, 3)
+decode input directly — the full preprocessed image is never materialised.
 
 Multi-tile escalation form: offsets may also be (b, k, 2) — k tiles per
 image (``tiling.escalation_offsets`` plans).  The grid becomes b*k steps
@@ -34,21 +36,24 @@ exactly the same MXU path as the single-tile hot path.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.transforms import IMAGENET_MEAN, IMAGENET_STD
-from repro.kernels.fused_preprocess import interp_affine, interp_matrices
+from repro.kernels.fused_preprocess import (affine_constants, from_planar,
+                                            interp_affine, interp_matrices,
+                                            load_planar, to_planar)
 
 
-def _kernel(img_ref, ry_ref, rx_ref, scale_ref, bias_ref, out_ref):
-    img = img_ref[0].astype(jnp.float32)          # (H, W, 3)
+def _kernel(img_ref, ry_ref, rx_ref, out_ref, *, scale, bias):
     # ry (tile, H) / rx (W, tile) are this image's pre-sliced matrices;
     # the math is the staged kernel's interp_affine, shared verbatim
-    out_ref[0] = interp_affine(img, ry_ref[0], rx_ref[0],
-                               scale_ref[...], bias_ref[...])
+    outs = interp_affine(load_planar(img_ref), ry_ref[...], rx_ref[...],
+                         scale, bias)
+    for c in range(3):
+        out_ref[c] = outs[c]
 
 
 def slice_interp_matrices(offsets, *, H: int, W: int, resize: int,
@@ -73,17 +78,16 @@ def fused_tile_preprocess(raw, offsets, *, resize: int = 256,
     ``offsets`` is (b, 2) — one tile per image, output
     (b, tile, tile, 3) — or (b, k, 2) — a k-tile escalation plan per
     image, output (b*k, tile, tile, 3) flattened image-major (rows
-    [i*k, (i+1)*k) are image i's tiles).  Either way each output tile
-    equals ``extract_tiles(fused_preprocess(raw), <its offset>, tile)``
-    bit for bit, without materialising the (b, crop, crop, 3)
+    [i*k, (i+1)*k) are image i's tiles).  Each output tile equals
+    ``extract_tiles(fused_preprocess(raw), <its offset>, tile)`` up to
+    float reassociation, without materialising the (b, crop, crop, 3)
     intermediate; the multi-tile grid reads each raw image block k
-    times rather than replicating it.  interpret=True executes on CPU
-    (this container); interpret=False is the TPU target.  Not jitted
-    here: callers jit around it (the interpolation matrices are host
-    constants).
+    times rather than replicating it.  The kernel reads planar
+    (3, H, W) image blocks and writes planar (3, tile, tile) tiles.
+    interpret=True executes on CPU; interpret=False compiles for the
+    TPU.  Not jitted here: callers jit around it (the interpolation
+    matrices are host constants).
     """
-    mean = np.asarray(IMAGENET_MEAN if mean is None else mean, np.float32)
-    std = np.asarray(IMAGENET_STD if std is None else std, np.float32)
     b, H, W, C = raw.shape
     assert C == 3
     assert tile <= crop, f"tile {tile} exceeds crop {crop}"
@@ -93,20 +97,19 @@ def fused_tile_preprocess(raw, offsets, *, resize: int = 256,
     ry_t, rx_t = slice_interp_matrices(
         offsets.reshape(n, 2), H=H, W=W, resize=resize, crop=crop,
         tile=tile)
-    scale = jnp.asarray(1.0 / (255.0 * std))
-    bias = jnp.asarray(-mean / std)
+    scale, bias = affine_constants(mean, std)
 
-    return pl.pallas_call(
-        _kernel,
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, bias=bias),
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, H, W, 3), lambda i: (i // k, 0, 0, 0)),
-            pl.BlockSpec((1, tile, H), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, W, tile), lambda i: (i, 0, 0)),
-            pl.BlockSpec((3,), lambda i: (0,)),
-            pl.BlockSpec((3,), lambda i: (0,)),
+            pl.BlockSpec((None, 3, H, W), lambda i: (i // k, 0, 0, 0)),
+            pl.BlockSpec((None, tile, H), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, W, tile), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile, tile, 3), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, tile, tile, 3), jnp.float32),
+        out_specs=pl.BlockSpec((None, 3, tile, tile),
+                               lambda i: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 3, tile, tile), jnp.float32),
         interpret=interpret,
-    )(raw, ry_t, rx_t, scale, bias)
+    )(to_planar(raw), ry_t, rx_t)
+    return from_planar(out)

@@ -8,15 +8,20 @@ codewords in VMEM with *zero gathers* —
 * GF(2^4) multiply is computed CARRY-LESSLY (4 AND/shift/XOR partial
   products + 3 reduction steps mod x^4+x+1) instead of log/exp table
   lookups: gathers are the slow path on the TPU VPU, bitwise ops
-  vectorise perfectly across the (block, n, n+1) elimination state.
-* inverse(a) = a^14 by square-and-multiply (GF(16)* has order 15).
-* Berlekamp-Welch = masked-pivot Gaussian elimination, fully unrolled
-  over the static 16 columns x 15 rows of the (n, n+1) system.
-* the "pick k error-free positions" step replaces argsort with a rank
-  prefix-sum + one-hot permutation matmul (branch-free, MXU-able).
+  vectorise perfectly across the elimination state.
+* inverse(a) by a select chain over the 15 nonzero field elements.
+* Berlekamp-Welch = masked-pivot Gaussian elimination, unrolled over
+  the static 15 columns of the (15, 15) system.
+* the "pick k error-free positions" step replaces argsort with a
+  running rank and selects (branch-free).
 
-Block = 128 codewords/grid step: the elimination state is
-(128, 15, 16) int32 = 122 KB — comfortably VMEM-resident.  Oracle:
+Layout: codewords on the lane axis, up to 128 per grid step.  A
+per-codeword scalar is a (1, 128) row, a length-15 vector a (15, 128)
+array, the (15, 15) system a (15, 15, 128) array indexed column-first,
+so every block is lane-dense and every step is elementwise VPU work,
+static slices, or a float32 sublane reduction (the TPU compiler reduces
+float32 only; GF(16) symbols and indices are exact in it).  The
+elimination state is 15 x 16 x 128 int32 (sublane-padded) = 120 KB.  Oracle:
 repro.core.rs.jax_rs (itself validated against the numpy codec).
 
 Default code only (GF(16), n=15, k=12, t=1 — the paper's 48-bit
@@ -24,11 +29,8 @@ configuration); other codes fall back to jax_rs.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.rs.codec import RSCode, DEFAULT_CODE
@@ -49,10 +51,11 @@ COLS = NQ + NN    # unknowns x = [q_0..q_t, nu_0..nu_{t+k-1}], 15 total
 
 
 def _gf16_mul(a, b):
-    """Carry-less GF(16) multiply, branch-free, elementwise."""
-    res = jnp.zeros_like(a)
+    """Carry-less GF(16) multiply, branch-free, elementwise (operands
+    broadcast)."""
+    res = jnp.zeros(jnp.broadcast_shapes(a.shape, b.shape), jnp.int32)
     for i in range(M):
-        res = res ^ (jnp.where((b >> i) & 1 != 0, a << i, 0))
+        res = res ^ jnp.where((b >> i) & 1 != 0, a << i, 0)
     # reduce bits 6..4 mod x^4 + x + 1 (0b10011)
     for j in (6, 5, 4):
         res = jnp.where((res >> j) & 1 != 0, res ^ (0b10011 << (j - 4)),
@@ -61,179 +64,176 @@ def _gf16_mul(a, b):
 
 
 def _gf16_inv(a):
-    """a^-1 = a^14 (order of GF(16)* is 15); inv(0) := 0."""
-    a2 = _gf16_mul(a, a)
-    a4 = _gf16_mul(a2, a2)
-    a8 = _gf16_mul(a4, a4)
-    return _gf16_mul(a8, _gf16_mul(a4, a2))  # a^(8+4+2) = a^14
-
-
-@functools.lru_cache(maxsize=None)
-def _consts():
-    exp, _ = gf_np.tables(M)
-    xs = exp[:N].astype(np.int32)  # evaluation points alpha^0..alpha^14
-    powsQ = np.ones((N, NQ), np.int64)
-    powsN = np.ones((N, NN), np.int64)
+    """a^-1 by table (a select chain over the 15 nonzero elements);
+    inv(0) := 0."""
     g = gf_np.GF(M)
+    out = jnp.zeros_like(a)
+    for v in range(1, 1 << M):
+        out = jnp.where(a == v, int(g.inv(v)), out)
+    return out
+
+
+def _kernel(bits_ref, msg_ref, cw_ref, stat_ref):
+    """Codewords on the lane axis.  A per-codeword scalar is a (1, blk)
+    row, a length-N vector a (N, blk) array with its entries on
+    sublanes, and the B-W system A a (COLS, N, blk) array whose leading
+    index is the column: a column is a leading-index slice and a row
+    across columns is a sublane reduction.  Reductions run in float32
+    (every value is a GF(16) symbol or an index below 16, exact in
+    float32)."""
+    blk = bits_ref.shape[-1]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (N, blk), 0)
+    subf = sub.astype(jnp.float32)
+
+    def row_max(x, axis):
+        """Sublane max of a non-negative int array, kept as an axis."""
+        return jnp.max(x.astype(jnp.float32), axis=axis,
+                       keepdims=True).astype(jnp.int32)
+
+    # bits (M, N, blk) -> received symbols R (N, blk), MSB first
+    R = bits_ref[0]
+    for m in range(1, M):
+        R = (R << 1) | bits_ref[m]
+
+    # evaluation points alpha^i (N, blk)
+    exp, _ = gf_np.tables(M)
+    xs = jnp.zeros((N, blk), jnp.int32)
     for i in range(N):
-        for j in range(1, NQ):
-            powsQ[i, j] = g.mul(powsQ[i, j - 1], int(xs[i]))
-        for j in range(1, NN):
-            powsN[i, j] = g.mul(powsN[i, j - 1], int(xs[i]))
-    return xs, powsQ.astype(np.int32), powsN.astype(np.int32)
+        xs = jnp.where(sub == i, int(exp[i]), xs)
 
+    # the B-W system: column j < NQ is R_i x_i^j, column NQ + j is x_i^j
+    pows = [jnp.ones((N, blk), jnp.int32)]
+    for _ in range(1, max(NQ, NN)):
+        pows.append(_gf16_mul(pows[-1], xs))
+    A = jnp.stack([_gf16_mul(R, pows[j]) for j in range(NQ)]
+                  + [pows[j] for j in range(NN)])  # (COLS, N, blk)
 
-def _kernel(bits_ref, xs_ref, powsQ_ref, powsN_ref,
-            msg_ref, cw_ref, ok_ref, ncorr_ref):
-    bits = bits_ref[...].astype(jnp.int32)  # (B, N*M)
-    B = bits.shape[0]
-    xs = xs_ref[...]          # (N,)
-    powsQ = powsQ_ref[...]    # (N, NQ)
-    powsN = powsN_ref[...]    # (N, NN)
+    # masked-pivot RREF over the static COLS columns (jax_rs's order)
+    pivot_col = jnp.full((N, blk), COLS, jnp.int32)
+    r = jnp.zeros((1, blk), jnp.int32)
+    for c in range(COLS):
+        elig = (sub >= r) & (A[c] != 0)
+        pr = jnp.min(jnp.where(elig, subf, float(N)), axis=0,
+                     keepdims=True).astype(jnp.int32)
+        has = pr < N                          # (1, blk)
+        on_r = sub == r                       # (N, blk)
+        on_p = sub == pr
+        Ar = row_max(jnp.where(on_r, A, 0), 1)   # (COLS, 1, blk)
+        Ap = row_max(jnp.where(on_p, A, 0), 1)
+        # after the swap row r holds Ap; normalise it by its pivot
+        piv_row = _gf16_mul(Ap, _gf16_inv(Ap[c]))
+        A = jnp.where(has & on_r, piv_row,
+                      jnp.where(has & on_p, Ar, A))
+        # eliminate column c from every other row
+        f = jnp.where(has & ~on_r, A[c], 0)
+        A = A ^ _gf16_mul(piv_row, f)
+        pivot_col = jnp.where(on_r & has, c, pivot_col)
+        r = jnp.minimum(r + has.astype(jnp.int32), N)
 
-    # bits -> symbols (MSB first): weights built from iota (no captured
-    # constants allowed in a pallas kernel body)
-    w = (1 << (M - 1 - jax.lax.iota(jnp.int32, M)))
-    R = (bits.reshape(B, N, M) * w).sum(-1)  # (B, N)
+    # nullspace vector: first free column f; x[f] = 1 and
+    # x[pivot_col[row]] = A[row, f] (char 2: -a == a).  Only Q = x[:NQ]
+    # is needed.
+    free = jnp.full((1, blk), COLS, jnp.int32)
+    for j in range(COLS - 1, -1, -1):
+        is_piv = row_max((pivot_col == j).astype(jnp.int32), 0) > 0
+        free = jnp.where(is_piv, free, j)
+    A_free = jnp.zeros((N, blk), jnp.int32)
+    for j in range(COLS):
+        A_free = jnp.where(free == j, A[j], A_free)
+    Q = [(free == j).astype(jnp.int32)
+         ^ row_max(jnp.where(pivot_col == j, A_free, 0), 0)
+         for j in range(NQ)]
 
-    # build the B-W system A (B, N, COLS)
-    A = jnp.concatenate(
-        [_gf16_mul(R[:, :, None], powsQ[None]),
-         jnp.broadcast_to(powsN[None], (B, N, NN)).astype(jnp.int32)],
-        axis=2)
-
-    # masked-pivot RREF, unrolled over the static COLS columns
-    rows = N
-    cols = COLS
-    row_idx = jax.lax.iota(jnp.int32, rows)
-    pivot_col = jnp.full((B, rows), cols, jnp.int32)
-    r = jnp.zeros((B,), jnp.int32)
-    for c in range(cols):
-        colv = A[:, :, c]  # (B, rows)
-        eligible = (row_idx[None] >= r[:, None]) & (colv != 0)
-        has = eligible.any(axis=1)  # (B,)
-        pr = jnp.argmax(eligible, axis=1)  # first eligible row
-        # swap rows r <-> pr (select form; r == pr degenerates safely)
-        onehot_r = row_idx[None] == r[:, None]
-        onehot_p = row_idx[None] == pr[:, None]
-        Ar = (A * onehot_r[..., None]).sum(1)  # (B, cols)
-        Ap = (A * onehot_p[..., None]).sum(1)
-        swp = has[:, None, None]
-        A = jnp.where(swp & onehot_r[..., None], Ap[:, None, :], A)
-        A = jnp.where(swp & onehot_p[..., None] & ~onehot_r[..., None],
-                      Ar[:, None, :], A)
-        # normalise pivot row
-        piv = (A[:, :, c] * onehot_r).sum(1)  # (B,)
-        inv = _gf16_inv(piv)
-        Arow = (A * onehot_r[..., None]).sum(1)
-        Arow_n = _gf16_mul(Arow, inv[:, None])
-        A = jnp.where(swp & onehot_r[..., None], Arow_n[:, None, :], A)
-        # eliminate column c from all other rows
-        factors = jnp.where((~onehot_r) & has[:, None], A[:, :, c], 0)
-        Apiv = (A * onehot_r[..., None]).sum(1)  # (B, cols)
-        A = A ^ _gf16_mul(factors[..., None], Apiv[:, None, :])
-        pivot_col = jnp.where(onehot_r & has[:, None],
-                              jnp.int32(c), pivot_col)
-        r = jnp.minimum(r + has.astype(jnp.int32), rows)
-
-    # nullspace vector: first free column f; x[f] = 1,
-    # x[pivot_col[row]] = A[row, f] for every pivot row (char 2: -a == a).
-    # Pivot columns are distinct and never equal f, so XOR-accumulation
-    # of the one-hot contributions is exact.
-    col_ids = jax.lax.iota(jnp.int32, cols)
-    is_pivot = (pivot_col[:, :, None] == col_ids[None, None, :]).any(1)
-    free = jnp.argmin(is_pivot.astype(jnp.int32), axis=1)  # (B,)
-    x = (col_ids[None] == free[:, None]).astype(jnp.int32)  # (B, cols)
-    vals = jnp.take_along_axis(
-        A, jnp.broadcast_to(free[:, None, None], (B, rows, 1)),
-        axis=2)[:, :, 0]  # A[:, row, free] -> (B, rows)
-    scatter = (pivot_col[:, :, None] == col_ids[None, None, :])
-    x = x ^ (scatter * vals[:, :, None]).sum(1)
-
-    Q = x[:, :NQ]  # (B, NQ)
-    # Q(X_i) via unrolled Horner
-    qx = jnp.zeros((B, N), jnp.int32)
+    # Q(x_s) via Horner at every evaluation point
+    qx = jnp.zeros((N, blk), jnp.int32)
     for j in range(NQ - 1, -1, -1):
-        qx = _gf16_mul(qx, xs[None]) ^ Q[:, j:j + 1]
-    q_nonzero = (Q != 0).any(axis=1)
-    err = (qx == 0) & q_nonzero[:, None]  # (B, N)
+        qx = _gf16_mul(qx, xs) ^ Q[j]
+    q_nonzero = Q[0] != 0
+    for j in range(1, NQ):
+        q_nonzero = q_nonzero | (Q[j] != 0)
+    err = (qx == 0) & q_nonzero
 
-    # pick K error-free positions: rank prefix-sum + one-hot permutation
-    okpos = (~err).astype(jnp.int32)  # (B, N)
-    rank = jnp.cumsum(okpos, axis=1) - okpos  # rank among correct ones
-    sel = (okpos * (rank < K)) == 1  # (B, N) -> exactly K true (if >=K ok)
-    slot = jnp.where(sel, rank, K)  # (B, N) in [0..K]
-    perm = (slot[:, :, None]
-            == jax.lax.iota(jnp.int32, K)[None, None, :]
-            ).astype(jnp.int32)  # (B, N, K)
-    xs_sel = (perm * xs[None, :, None]).sum(1)  # (B, K)
-    ys_sel = (perm * R[:, :, None]).sum(1)      # (B, K)
+    # pick the first K error-free positions (running rank): row k of
+    # xs_sel / ys_sel holds the point / received symbol of the k-th
+    ok_pos = (~err).astype(jnp.int32)
+    rank = jnp.zeros((1, blk), jnp.int32)
+    xs_sel = jnp.zeros((N, blk), jnp.int32)
+    ys_sel = jnp.zeros((N, blk), jnp.int32)
+    for s_ in range(N):
+        ok_s = ok_pos[s_: s_ + 1] > 0
+        take = ok_s & (rank < K) & (sub == rank)
+        xs_sel = jnp.where(take, xs[s_: s_ + 1], xs_sel)
+        ys_sel = jnp.where(take, R[s_: s_ + 1], ys_sel)
+        rank = rank + ok_pos[s_: s_ + 1]
 
-    # Lagrange re-interpolation evaluated at all N points (unrolled)
-    # denom_i = prod_{j!=i} (Xs_i ^ Xs_j); wgt_i = y_i * inv(denom_i)
-    denom = jnp.ones((B, K), jnp.int32)
+    # Lagrange re-interpolation through the K selected points,
+    # evaluated at all N points: wgt_i = y_i / prod_{j!=i}(X_i ^ X_j)
+    denom = jnp.ones((N, blk), jnp.int32)
     for j in range(K):
-        d = xs_sel ^ xs_sel[:, j:j + 1]
-        d = jnp.where(jax.lax.iota(jnp.int32, K)[None] == j, 1, d)
+        d = jnp.where(sub == j, 1, xs_sel ^ xs_sel[j: j + 1])
         denom = _gf16_mul(denom, d)
-    wgt = _gf16_mul(ys_sel, _gf16_inv(denom))  # (B, K)
-    # P(x) at each eval point: sum_i wgt_i * prod_{j != i} (x ^ Xs_j)
-    P_at = jnp.zeros((B, N), jnp.int32)
-    for i in range(K):
-        numer = jnp.ones((B, N), jnp.int32)
-        for j in range(K):
-            if j == i:
-                continue
-            numer = _gf16_mul(numer, xs[None] ^ xs_sel[:, j:j + 1])
-        P_at = P_at ^ _gf16_mul(numer, wgt[:, i:i + 1])
+    wgt = _gf16_mul(ys_sel, _gf16_inv(denom))
+    # P(x_s) = sum_i wgt_i * prod_{j!=i}(x_s ^ X_j), the products from
+    # prefix and suffix runs
+    diffs = [xs ^ xs_sel[j: j + 1] for j in range(K)]
+    pre = [jnp.ones((N, blk), jnp.int32)]
+    for j in range(K - 1):
+        pre.append(_gf16_mul(pre[-1], diffs[j]))
+    suf = jnp.ones((N, blk), jnp.int32)
+    P_at = jnp.zeros((N, blk), jnp.int32)
+    for i in range(K - 1, -1, -1):
+        P_at = P_at ^ _gf16_mul(_gf16_mul(pre[i], suf), wgt[i: i + 1])
+        suf = _gf16_mul(suf, diffs[i])
 
-    n_err = (P_at != R).sum(axis=1)
+    n_err = jnp.sum((P_at != R).astype(jnp.float32), axis=0,
+                    keepdims=True).astype(jnp.int32)
     ok = (n_err <= T) & q_nonzero
-    cw = jnp.where(ok[:, None], P_at, R)  # (B, N)
-    # symbols -> bits
-    sh = M - 1 - jax.lax.iota(jnp.int32, M)
-    cw_bits = ((cw[:, :, None] >> sh) & 1).reshape(B, N * M)
-    msg_ref[...] = cw_bits[:, : K * M]
-    cw_ref[...] = cw_bits
-    ok_ref[...] = ok.astype(jnp.int32)
-    ncorr_ref[...] = jnp.where(ok, n_err, -1).astype(jnp.int32)
+    cw = jnp.where(ok, P_at, R)
+    for m in range(M):  # symbols -> bits
+        bit = (cw >> (M - 1 - m)) & 1
+        cw_ref[m] = bit
+        msg_ref[m] = bit[:K]
+    stat_ref[0:1, :] = ok.astype(jnp.int32)
+    stat_ref[1:2, :] = jnp.where(ok, n_err, -1)
 
 
 def rs_decode_batch(bits, *, code: RSCode = DEFAULT_CODE,
                     block: int = 128, interpret: bool = True):
     """bits (B, n*m) int -> dict(message_bits, codeword_bits, ok,
-    n_corrected).  Pallas kernel for the default (15,12) GF(16) code."""
+    n_corrected).  Pallas kernel for the default (15,12) GF(16) code.
+
+    The kernel works on the transpose: bit m of symbol s of codeword b
+    at [m, s, b], codewords on the lane axis, ``block`` of them per grid
+    step (a batch of at most ``block`` is one step whose block is the
+    whole array, so small batches carry no padding)."""
     if (code.m, code.n, code.k) != (M, N, K):
         from repro.core.rs import jax_rs
         return jax_rs.make_batch_decoder(code)(bits)
     B = bits.shape[0]
-    blk = min(block, B)
-    Bp = -(-B // blk) * blk
-    bits_p = jnp.pad(bits.astype(jnp.int32), ((0, Bp - B), (0, 0)))
-    xs_np, powsQ_np, powsN_np = _consts()
-    grid = (Bp // blk,)
-    out = pl.pallas_call(
+    block = min(block, B)
+    Bp = -(-B // block) * block
+    bits_t = jnp.pad(bits.astype(jnp.int32), ((0, Bp - B), (0, 0)))
+    bits_t = bits_t.reshape(Bp, N, M).transpose(2, 1, 0)
+
+    def spec(*lead):
+        return pl.BlockSpec(lead + (block,),
+                            lambda i: (0,) * len(lead) + (i,))
+
+    msg, cw, stat = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((blk, N * M), lambda i: (i, 0)),
-                  pl.BlockSpec((N,), lambda i: (0,)),
-                  pl.BlockSpec((N, NQ), lambda i: (0, 0)),
-                  pl.BlockSpec((N, NN), lambda i: (0, 0))],
-        out_specs=[
-            pl.BlockSpec((blk, K * M), lambda i: (i, 0)),
-            pl.BlockSpec((blk, N * M), lambda i: (i, 0)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-        ],
+        grid=(Bp // block,),
+        in_specs=[spec(M, N)],
+        out_specs=[spec(M, K), spec(M, N), spec(2)],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp, K * M), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, N * M), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((M, K, Bp), jnp.int32),
+            jax.ShapeDtypeStruct((M, N, Bp), jnp.int32),
+            jax.ShapeDtypeStruct((2, Bp), jnp.int32),
         ],
         interpret=interpret,
-    )(bits_p, jnp.asarray(xs_np), jnp.asarray(powsQ_np),
-      jnp.asarray(powsN_np))
-    msg, cw, ok, ncorr = out
-    return {"message_bits": msg[:B], "codeword_bits": cw[:B],
-            "ok": ok[:B].astype(bool), "n_corrected": ncorr[:B]}
+    )(bits_t)
+
+    def unplanar(x):
+        return x.transpose(2, 1, 0).reshape(Bp, -1)[:B]
+
+    return {"message_bits": unplanar(msg), "codeword_bits": unplanar(cw),
+            "ok": stat[0, :B].astype(bool), "n_corrected": stat[1, :B]}

@@ -151,10 +151,7 @@ def _probe_cfg(cfg, depth_groups):
 
 
 def _analyze(compiled, n_chips):
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-        cost = cost[0] if cost else {}
-    cost = dict(cost)
+    cost = dict(compiled.cost_analysis())
     coll = hlo_analysis.collective_stats(compiled.as_text())
     mem = compiled.memory_analysis()
     memd = {}

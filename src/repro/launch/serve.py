@@ -114,11 +114,19 @@ class DetectionService:
 
     # -- Algorithm 2 + lane-executor streaming -----------------------------
     def serve(self, batches: Iterable, *,
-              use_scheduler: bool = True) -> ServiceReport:
+              use_scheduler: bool = True,
+              on_result: Optional[Callable[[int, dict], None]] = None
+              ) -> ServiceReport:
         """Run a stream of (possibly ragged) batches through the lane
         executor.  With the scheduler on, each request batch is split
         into LPT-placed mini-batch tasks first (Algorithm 2); the task
-        slices then flow through the executor as the work stream."""
+        slices then flow through the executor as the work stream.
+
+        ``on_result(i, res)`` receives work item ``i``'s result, pad
+        rows sliced off, as it leaves the executor.  Work items are
+        contiguous slices of the batches, in order; item ``i`` ran
+        with the pipeline's batch key ``seq0 + i``, where ``seq0`` is
+        the pipeline's batch counter when ``serve`` was called."""
         mon = sched_lib.StragglerMonitor()
         lane_loads: Optional[List[float]] = None
         work: List[Tuple[np.ndarray, int]] = []  # (padded slice, true b)
@@ -170,6 +178,8 @@ class DetectionService:
                     res[k] = v[:true_b]   # slice pad rows off
             n_img_box[0] += true_b
             mon.complete(tid)
+            if on_result is not None:
+                on_result(tid, res)
 
         t0 = time.perf_counter()
         out = self.pipe.run_stream(feed(), lanes=self.lanes,
@@ -435,24 +445,6 @@ def run_fleet(cfg: DetectionConfig, params, *, replicas: int,
     }
 
 
-def enable_compilation_cache(path: str, *, min_entry_bytes: int = 0,
-                             min_compile_secs: float = 0.0) -> bool:
-    """Point jax's persistent compilation cache at ``path`` so a service
-    restart reuses every jitted detection graph (ingest/decode/RS and
-    the fused fast path) instead of recompiling — the jit warm-up is the
-    dominant cold-start cost for a serving replica.  Returns False when
-    this jax build has no persistent cache (knob is then a no-op)."""
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          min_entry_bytes)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_secs)
-        return True
-    except Exception:
-        return False
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=8)
@@ -490,18 +482,13 @@ def main():
                          "config before building the service, persist "
                          "the winner in the autotune cache, and serve "
                          "with it (implies --schedule auto)")
-    ap.add_argument("--autotune-cache", default="",
-                    help="schedule-cache JSON path (default: "
-                         "decode_schedules.json next to "
-                         "--compilation-cache when given, else "
-                         "experiments/autotune/decode_schedules.json)")
+    ap.add_argument("--autotune-cache",
+                    default="experiments/autotune/decode_schedules.json",
+                    help="schedule-cache JSON path")
     ap.add_argument("--unfused-decode", action="store_true",
                     help="disable the fused Pallas extractor kernel "
                          "(decode runs the unfused XLA graph; warmup "
                          "then profiles and allocates lanes for that)")
-    ap.add_argument("--compilation-cache", default="",
-                    help="directory for jax's persistent compilation "
-                         "cache (reused across service restarts)")
     ap.add_argument("--online", action="store_true",
                     help="request-level serving: DetectionServer + "
                          "open-loop Poisson load instead of the "
@@ -571,10 +558,8 @@ def main():
                          "--escalate-tiles > 1)")
     args = ap.parse_args()
 
-    if args.compilation_cache:
-        on = enable_compilation_cache(args.compilation_cache)
-        print(f"compilation cache: "
-              f"{args.compilation_cache if on else 'unsupported'}")
+    from repro.launch.compile_cache import init_compile_cache
+    print(f"compilation cache: {init_compile_cache()}")
 
     from repro.core.extractor import init_extractor, pack_params
     from repro.core.rs.codec import DEFAULT_CODE
@@ -582,11 +567,6 @@ def main():
                             n_bits=DEFAULT_CODE.codeword_bits)
 
     cache_path = args.autotune_cache
-    if not cache_path:
-        cache_path = ((args.compilation_cache.rstrip("/")
-                       + "/decode_schedules.json")
-                      if args.compilation_cache else
-                      "experiments/autotune/decode_schedules.json")
     schedule = args.schedule
     if args.autotune:
         # populate (or reuse) the schedule cache before the service is

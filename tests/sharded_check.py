@@ -1,10 +1,10 @@
 """Subprocess helper for tests/test_lanes.py: forces a 4-device CPU
 topology (XLA_FLAGS must be set before jax initialises, hence the
 separate process) and checks that the data-parallel sharded
-``DetectionPipeline.run_batch`` is bit-identical to the single-device
-path, including for a ragged batch that needs padding, and that the
-tile-first fused ingest matches the staged full-image path on the
-sharded mesh.
+``DetectionPipeline.run_batch`` matches the single-device path (equal
+decisions, logits within the cross-program tolerance), including for a
+ragged batch that needs padding, and that the tile-first fused ingest
+matches the staged full-image path on the sharded mesh.
 
 Not named test_*.py on purpose — pytest must not collect it.
 """
@@ -24,7 +24,8 @@ if SRC not in sys.path:
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core.detect import DetectionConfig, DetectionPipeline  # noqa: E402
+from repro.core.detect import (CROSS_PROGRAM_LOGIT_ATOL,  # noqa: E402
+                               DetectionConfig, DetectionPipeline)
 from repro.core.extractor import init_extractor  # noqa: E402
 from repro.core.rs.codec import DEFAULT_CODE  # noqa: E402
 from repro.launch.mesh import make_detection_mesh  # noqa: E402
@@ -54,9 +55,10 @@ def main():
         assert np.array_equal(out_m["ok"], out_s["ok"]), f"b={b}: ok diverge"
         assert np.array_equal(out_m["n_corrected"], out_s["n_corrected"])
         assert out_m["logits"].shape == (b, DEFAULT_CODE.codeword_bits)
-        # decode is per-image, so sharding must not move the floats either
-        assert np.array_equal(out_m["logits"], out_s["logits"]), \
-            f"b={b}: logits diverge"
+        # the sharded program may reassociate float sums differently
+        np.testing.assert_allclose(out_m["logits"], out_s["logits"],
+                                   rtol=0, atol=CROSS_PROGRAM_LOGIT_ATOL,
+                                   err_msg=f"b={b}: logits diverge")
 
     # tile-first fused ingest == staged full-image ingest on the 4-device
     # mesh (cfg above runs tile-first by default; rerun staged and compare)
@@ -68,9 +70,12 @@ def main():
         raw, mesh=mesh4, key=key)
     out_st = DetectionPipeline(cfg_staged, params).run_batch(
         raw, mesh=mesh4, key=key)
-    for f in ("message_bits", "ok", "n_corrected", "logits"):
+    for f in ("message_bits", "ok", "n_corrected"):
         assert np.array_equal(out_tf[f], out_st[f]), \
             f"sharded tile-first vs staged: {f} diverges"
+    np.testing.assert_allclose(out_tf["logits"], out_st["logits"], rtol=0,
+                               atol=CROSS_PROGRAM_LOGIT_ATOL,
+                               err_msg="sharded tile-first vs staged")
     print("OK")
 
 

@@ -2,9 +2,10 @@
 
 Contracts added by the schedule/precision PR:
 
-* the blocked kernel (any batch_block x channel_tile point) is fp32
-  bit-identical to the flat kernel and the unfused graph — the
-  schedule is a pure throughput knob;
+* the blocked kernel (any batch_block x channel_tile point) agrees
+  with the flat kernel and the unfused graph under the cross-program
+  contract (same hard bits, logits within CROSS_PROGRAM_LOGIT_ATOL) —
+  the schedule is a pure throughput knob;
 * the int8 rung: pack-time per-channel weight scales round-trip, the
   decode path is batch-stable, and on a margin-bearing (watermarked)
   workload int8 reaches decision agreement 1.0 with fp32;
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.detect import CROSS_PROGRAM_LOGIT_ATOL
 from repro.core.extractor import (extractor_forward, init_encoder,
                                   init_extractor, pack_params,
                                   quantize_weight_int8, unpack_params,
@@ -31,6 +33,15 @@ from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.autotune import Schedule
 from repro.kernels.fused_extractor import fused_extractor_blocked
+
+
+def assert_same_decode(a, b, err_msg=""):
+    """Two programs' logits: same hard bits, values within the
+    cross-program tolerance."""
+    a, b = np.asarray(a), np.asarray(b)
+    np.testing.assert_array_equal(a > 0, b > 0, err_msg=err_msg)
+    np.testing.assert_allclose(a, b, rtol=0, atol=CROSS_PROGRAM_LOGIT_ATOL,
+                               err_msg=err_msg)
 
 
 def _tiles(b, l, seed=0):
@@ -73,26 +84,25 @@ def _margined_workload(tile=32, batch=6, channels=8, depth=2):
 @pytest.mark.parametrize("tile", [32, 64, 128])
 def test_blocked_fp32_bit_identical_to_flat(tile):
     """Every blocked schedule point reproduces the flat grid=(b,) kernel
-    (and hence the unfused graph) bit for bit at fp32."""
+    (and the unfused graph) at fp32, under the cross-program contract."""
     params = _params(tile)
     packed = pack_params(params)
     tiles = _tiles(4, tile, seed=tile)
     flat = np.asarray(jax.jit(
         lambda t: kops.fused_extractor(t, packed))(tiles))
-    np.testing.assert_array_equal(
-        flat, np.asarray(jax.jit(extractor_forward)(params, tiles)))
+    assert_same_decode(flat, jax.jit(extractor_forward)(params, tiles))
     for bb, ct in ((1, 0), (2, 0), (4, 0), (1, 4), (2, 3)):
         blocked = np.asarray(jax.jit(
             lambda t, _bb=bb, _ct=ct: fused_extractor_blocked(
                 t, packed, batch_block=_bb, channel_tile=_ct))(tiles))
-        np.testing.assert_array_equal(
-            blocked, flat, err_msg=f"bb={bb} ct={ct} tile={tile}")
+        assert_same_decode(blocked, flat,
+                           err_msg=f"bb={bb} ct={ct} tile={tile}")
 
 
 @pytest.mark.parametrize("b", [1, 3, 5, 7])
 def test_blocked_ragged_batches(b):
     """Ragged batches (b % batch_block != 0) are zero-padded and sliced;
-    pad rows are inert so every row matches the flat kernel bitwise."""
+    pad rows are inert so every row matches the flat kernel."""
     params = _params(32)
     packed = pack_params(params)
     full = np.asarray(jax.jit(
@@ -101,12 +111,12 @@ def test_blocked_ragged_batches(b):
     part = np.asarray(jax.jit(
         lambda t: kops.fused_extractor(t, packed, schedule=sched))(
             _tiles(7, 32)[:b]))
-    np.testing.assert_array_equal(part, full[:b])
+    assert_same_decode(part, full[:b])
 
 
 def test_ops_schedule_dispatch():
     """kops.fused_extractor(schedule=None) runs the flat kernel;
-    a Schedule runs the blocked kernel — fp32 outputs identical."""
+    a Schedule runs the blocked kernel — fp32 outputs agree."""
     params = _params(32)
     packed = pack_params(params)
     tiles = _tiles(3, 32)
@@ -114,7 +124,7 @@ def test_ops_schedule_dispatch():
         lambda t: kops.fused_extractor(t, packed))(tiles))
     c = np.asarray(jax.jit(lambda t: kops.fused_extractor(
         t, packed, schedule=Schedule(2, 0, True)))(tiles))
-    np.testing.assert_array_equal(a, c)
+    assert_same_decode(a, c)
 
 
 # ---------------------------------------------------------------------------
@@ -156,24 +166,20 @@ def test_int8_pack_structure_and_unpack():
 
 def test_int8_batch_stable_and_schedules_agree():
     """The int8 path quantizes activations per ROW, so it stays
-    batch-stable, and flat vs blocked schedules agree bitwise at full
-    channel width (same quantization, same accumulation order).
-    Channel-tiled int8 is float-level only — the dequant multiply-add
-    chain may fuse differently per tile width — so ct > 0 asserts ulp
-    closeness and identical hard bits instead."""
+    batch-stable: a sub-batch and the flat vs blocked schedules (full
+    width or channel-tiled) agree under the cross-program contract."""
     params = _params(32, channels=16, depth=3)
     pk = pack_params(params, "int8")
     tiles = _tiles(5, 32, seed=4)
     flat = jax.jit(lambda t: kops.fused_extractor(t, pk))
     full = np.asarray(flat(tiles))
-    np.testing.assert_array_equal(np.asarray(flat(tiles[:2])), full[:2])
+    assert_same_decode(flat(tiles[:2]), full[:2])
     blocked = np.asarray(jax.jit(lambda t: kops.fused_extractor(
         t, pk, schedule=Schedule(2, 0, True)))(tiles))
-    np.testing.assert_array_equal(blocked, full)
+    assert_same_decode(blocked, full)
     ct = np.asarray(jax.jit(lambda t: kops.fused_extractor(
         t, pk, schedule=Schedule(1, 4, True)))(tiles))
-    np.testing.assert_allclose(ct, full, atol=1e-5)
-    np.testing.assert_array_equal(ct > 0, full > 0)
+    assert_same_decode(ct, full)
 
 
 def test_int8_matches_dequant_oracle():
@@ -346,7 +352,8 @@ def test_resolve_schedule_modes(tmp_path, capsys):
 def test_engines_identical_under_tuned_schedule():
     """decode_schedule reaches detect_batch / run_batch / the lane
     executor without perturbing fp32 results: a tuned-schedule pipeline
-    equals the flat-schedule one bitwise on every engine output."""
+    matches the flat-schedule one on every engine output (equal
+    decisions, logits within the cross-program tolerance)."""
     from repro.core.detect import DetectionConfig, DetectionPipeline
     params = _params(16, n_bits=DEFAULT_CODE.codeword_bits,
                      channels=8, depth=2)
@@ -366,10 +373,12 @@ def test_engines_identical_under_tuned_schedule():
 
     flat, tuned = run("flat"), run("bb2-ct0-db")
     for eng in ("batch", "sharded"):
-        for f in ("message_bits", "ok", "logits"):
+        for f in ("message_bits", "ok"):
             np.testing.assert_array_equal(
                 np.asarray(flat[eng][f]), np.asarray(tuned[eng][f]),
                 err_msg=f"{eng}/{f}")
+        assert_same_decode(flat[eng]["logits"], tuned[eng]["logits"],
+                           err_msg=eng)
 
 
 def test_config_rejects_bad_schedule():

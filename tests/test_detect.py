@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.detect import (DetectionConfig, DetectionPipeline,
+from repro.core.detect import (CROSS_PROGRAM_LOGIT_ATOL, DetectionConfig,
+                               DetectionPipeline,
                                binomial_threshold, verify_against_key)
 from repro.core.extractor import (encoder_forward, extractor_forward,
                                   init_encoder, init_extractor)
@@ -165,9 +166,10 @@ def test_binomial_threshold_fails_closed_for_short_keys():
 
 
 def test_tile_first_matches_staged_all_engines(tiny_trained):
-    """The tile-first fused ingest must be bit-identical to the staged
-    full-image path on every execution engine: the fused detect_batch,
-    the lane executor at 1 and 4 lanes, and the sharded run_batch."""
+    """The tile-first fused ingest must match the staged full-image path
+    on every execution engine — the fused detect_batch, the lane
+    executor at 1 and 4 lanes, and the sharded run_batch: equal
+    decisions, logits within the cross-program tolerance."""
     params, tcfg, _ = tiny_trained
     mk = lambda tf: DetectionConfig(
         tile=16, img_size=32, resize_src=40, mode="qrmark",
@@ -195,10 +197,14 @@ def test_tile_first_matches_staged_all_engines(tiny_trained):
             "lanes4": collect(pipe.run_stream(data, lanes=4)["results"]),
         }
     for engine in ("batch", "sharded", "lanes1", "lanes4"):
-        for field in ("message_bits", "ok", "logits"):
+        for field in ("message_bits", "ok"):
             np.testing.assert_array_equal(
                 outs[True][engine][field], outs[False][engine][field],
                 err_msg=f"{engine}/{field} diverges tile-first vs staged")
+        np.testing.assert_allclose(
+            outs[True][engine]["logits"], outs[False][engine]["logits"],
+            rtol=0, atol=CROSS_PROGRAM_LOGIT_ATOL,
+            err_msg=f"{engine}/logits diverge tile-first vs staged")
 
 
 def test_end_to_end_detection_of_watermarked_images(tiny_trained):

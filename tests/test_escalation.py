@@ -225,13 +225,23 @@ def test_escalation_recovers_noised_round1_tile(workload):
 
 
 def test_margin_trigger_catches_spurious_all_zero_codeword(workload):
-    """A flat tile yields ~zero logits -> all-zero bits, which IS a
-    valid RS codeword (linear code): RS reports ok on garbage.  The
-    thin-margin trigger escalates anyway and recovers the real key."""
+    """A flat tile carrying the all-zero codeword's spread-spectrum
+    patterns at a thin amplitude yields slightly negative logits ->
+    all-zero bits, which IS a valid RS codeword (linear code): RS
+    reports ok on garbage.  The thin-margin trigger escalates anyway and
+    recovers the real key.  (A bare flat tile does not serve: the
+    zero-padded highpass leaves edge correlations of +-0.08 whose signs
+    are not all negative.)"""
+    from repro.core.transforms import IMAGENET_STD
     w = workload
     key = jax.random.key(5)
     p1 = DetectionPipeline(_cfg(1), w["dec"], ground_truth_bits=w["msg"])
-    raw_flat = _corrupt_round1_tile(w["raw"], p1, key, fill=128.0)
+    # -0.35 * sum of the unit-norm patterns, in normalised units, is a
+    # logit of about -0.3 per bit (below the 0.6 margin, above the edge
+    # correlations); mapped back to raw pixel units per channel
+    delta = -0.35 * np.asarray(w["dec"]["corr"]).sum(axis=0)
+    fill = 128.0 + delta * 255.0 * np.asarray(IMAGENET_STD)
+    raw_flat = _corrupt_round1_tile(w["raw"], p1, key, fill=fill)
     o1 = p1.detect_batch(raw_flat, key=key)
     assert o1["ok"].all(), "expected the spurious all-zero decode"
     assert o1["match"].mean() == 0.0

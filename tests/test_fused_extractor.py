@@ -1,17 +1,28 @@
-"""Fused extractor decode kernel: fp32 bit-exactness vs the unfused
-``extractor_forward``, semantic parity with the conv-formulation oracle,
-the bf16 precision policy, packed-params round-trip, and end-to-end
-engine equality through the detection pipeline."""
+"""Fused extractor decode kernel: fp32 agreement with the unfused
+``extractor_forward`` under the cross-program contract (docs/api.md),
+semantic parity with the conv-formulation oracle, the bf16 precision
+policy, packed-params round-trip, and end-to-end engine agreement
+through the detection pipeline."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.detect import CROSS_PROGRAM_LOGIT_ATOL
 from repro.core.extractor import (extractor_forward, init_extractor,
                                   pack_params, unpack_params)
 from repro.core.rs.codec import DEFAULT_CODE
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+
+
+def assert_same_decode(a, b, err_msg=""):
+    """Two programs' logits: same hard bits, values within the
+    cross-program tolerance."""
+    a, b = np.asarray(a), np.asarray(b)
+    np.testing.assert_array_equal(a > 0, b > 0, err_msg=err_msg)
+    np.testing.assert_allclose(a, b, rtol=0, atol=CROSS_PROGRAM_LOGIT_ATOL,
+                               err_msg=err_msg)
 
 
 def _tiles(b, l, seed=0):
@@ -33,16 +44,17 @@ def _params(l, *, corr=True, n_bits=60, channels=8, depth=2, seed=0):
 @pytest.mark.parametrize("corr", [True, False])
 @pytest.mark.parametrize("tile", [32, 64, 128])
 def test_fused_fp32_bit_exact_vs_unfused(tile, corr):
-    """The tentpole contract: the fp32 kernel is bit-identical to the
-    unfused extractor_forward graph (they share the packed matmul body),
-    with and without the correlation bank, at every tile size."""
+    """The tentpole contract: the fp32 kernel matches the unfused
+    extractor_forward graph (same per-tap dots and epilogue) under the
+    cross-program contract, with and without the correlation bank, at
+    every tile size."""
     params = _params(tile, corr=corr)
     tiles = _tiles(2, tile, seed=tile)
     packed = pack_params(params)
     fused = np.asarray(jax.jit(
         lambda t: kops.fused_extractor(t, packed))(tiles))
     unfused = np.asarray(jax.jit(extractor_forward)(params, tiles))
-    np.testing.assert_array_equal(fused, unfused)
+    assert_same_decode(fused, unfused)
     # and both match the original conv/einsum formulation semantically
     oracle = np.asarray(jax.jit(kref.fused_extractor_ref)(params, tiles))
     np.testing.assert_allclose(fused, oracle, atol=2e-5, rtol=1e-4)
@@ -50,14 +62,14 @@ def test_fused_fp32_bit_exact_vs_unfused(tile, corr):
 
 @pytest.mark.parametrize("b", [1, 3, 5])
 def test_fused_ragged_batches(b):
-    """Batch-stability: every row of a size-b batch equals the same row
-    of a larger batch (ragged serving slices must be inert)."""
+    """Batch-stability: every row of a size-b batch matches the same
+    row of a larger batch (ragged serving slices must be inert)."""
     params = _params(32)
     packed = pack_params(params)
     f = jax.jit(lambda t: kops.fused_extractor(t, packed))
     full = np.asarray(f(_tiles(5, 32)))
     part = np.asarray(f(_tiles(5, 32)[:b]))
-    np.testing.assert_array_equal(part, full[:b])
+    assert_same_decode(part, full[:b])
 
 
 def test_fused_bf16_logit_tolerance():
@@ -123,9 +135,10 @@ def _engine_outputs(cfg, params, raw, stream):
 @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
 def test_decode_engines_bit_identical(dtype):
     """Every engine — the fused single-jit fast path (detect_batch),
-    the sharded run_batch, and the lane executor — produces identical
-    message_bits/ok/logits for the same keys; and in fp32 the fused
-    kernel pipelines equal the unfused ones bit for bit."""
+    the sharded run_batch, and the lane executor — produces equal
+    message_bits/ok and logits within the cross-program tolerance for
+    the same keys; in fp32 the fused kernel pipelines match the unfused
+    ones the same way, and one program rerun is bitwise."""
     from repro.core.detect import DetectionConfig
     params = _params(16, n_bits=DEFAULT_CODE.codeword_bits,
                      channels=8, depth=2)
@@ -142,20 +155,25 @@ def test_decode_engines_bit_identical(dtype):
         return DetectionConfig(**base)
 
     fused = _engine_outputs(mk(), params, raw, stream)
-    # detect_batch and run_batch share the key -> must agree exactly
-    for f in ("message_bits", "ok", "logits"):
+    # detect_batch and run_batch share the key: two programs
+    for f in ("message_bits", "ok"):
         np.testing.assert_array_equal(
             fused["batch"][f], fused["sharded"][f],
             err_msg=f"batch vs sharded {f} ({dtype})")
+    assert_same_decode(fused["batch"]["logits"], fused["sharded"]["logits"],
+                       err_msg=f"batch vs sharded ({dtype})")
     assert fused["lanes"]["logits"].shape == (8, DEFAULT_CODE.codeword_bits)
     if dtype == "fp32":
         unfused = _engine_outputs(mk(fused_decode=False), params, raw,
                                   stream)
         for eng in ("batch", "sharded", "lanes"):
-            for f in ("message_bits", "ok", "logits"):
+            for f in ("message_bits", "ok"):
                 np.testing.assert_array_equal(
                     fused[eng][f], unfused[eng][f],
                     err_msg=f"fused vs unfused {eng}/{f}")
+            assert_same_decode(fused[eng]["logits"],
+                               unfused[eng]["logits"],
+                               err_msg=f"fused vs unfused {eng}")
     else:
         # the lane executor must reproduce the fused fast path bitwise
         # under bf16 too: rerun the stream through a fresh pipeline at a

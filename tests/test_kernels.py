@@ -1,11 +1,13 @@
 """Per-kernel shape/dtype sweeps: pallas_call (interpret mode) vs the
-pure-jnp oracle in kernels/ref.py."""
+pure-jnp oracle in kernels/ref.py.  Tile-first and staged ingest are two
+programs, compared under the cross-program contract (docs/api.md)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import tiling
+from repro.core.detect import CROSS_PROGRAM_LOGIT_ATOL
 from repro.kernels.fused_preprocess import fused_preprocess
 from repro.kernels.fused_tile_preprocess import fused_tile_preprocess
 from repro.kernels import ref as kref
@@ -65,12 +67,19 @@ def _tile_geometry(tile):
     return crop, crop + max(tile // 4, 8), crop + 32
 
 
+# tile-first vs staged ingest: the same interpolation sums in two
+# programs, so the pixels may differ by float reassociation only
+# (normalised pixels are O(1); a few ulp)
+INGEST_ATOL = 1e-5
+
+
 @pytest.mark.parametrize("strategy", tiling.STRATEGIES)
 @pytest.mark.parametrize("tile", [32, 64, 128])
 def test_fused_tile_preprocess_bit_exact_vs_staged(strategy, tile):
     """The tentpole contract: slicing the interpolation matrices before
-    the matmuls == slicing the full preprocessed image after them, bit
-    for bit, for every strategy and tile size."""
+    the matmuls == slicing the full preprocessed image after them, for
+    every strategy and tile size, up to float reassociation between the
+    two programs."""
     crop, resize, raw_hw = _tile_geometry(tile)
     rng = np.random.default_rng(tile)
     raw = jnp.asarray(rng.integers(0, 256, (2, raw_hw, raw_hw, 3),
@@ -84,7 +93,8 @@ def test_fused_tile_preprocess_bit_exact_vs_staged(strategy, tile):
     full = fused_preprocess(raw, resize=resize, crop=crop, interpret=True)
     staged = tiling.extract_tiles(full, offs, tile)
     assert out.shape == (2, tile, tile, 3)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(staged))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(staged),
+                               rtol=0, atol=INGEST_ATOL)
 
 
 @pytest.mark.parametrize("b", [1, 3])
@@ -99,8 +109,9 @@ def test_fused_tile_preprocess_ragged_batches(b):
     out = fused_tile_preprocess(raw, offs, resize=72, crop=64, tile=32,
                                 interpret=True)
     full = fused_preprocess(raw, resize=72, crop=64, interpret=True)
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(tiling.extract_tiles(full, offs, 32)))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(tiling.extract_tiles(full, offs, 32)),
+        rtol=0, atol=INGEST_ATOL)
 
 
 def test_fused_tile_preprocess_matches_oracle():
@@ -119,7 +130,8 @@ def test_fused_tile_preprocess_matches_oracle():
 
 def test_fused_tile_preprocess_logits_bit_exact():
     """End of the ingest contract: the extractor's logits on tile-first
-    tiles equal those on staged preprocess -> select_tiles_per_image."""
+    tiles match those on staged preprocess -> select_tiles_per_image
+    (same hard bits, logits within the cross-program tolerance)."""
     from repro.core.extractor import extractor_forward, init_extractor
     rng = np.random.default_rng(5)
     raw = jnp.asarray(rng.integers(0, 256, (3, 96, 96, 3),
@@ -136,9 +148,10 @@ def test_fused_tile_preprocess_logits_bit_exact():
     tiles_staged, offs2 = tiling.select_tiles_per_image(
         "random_grid", keys, full, 32)
     np.testing.assert_array_equal(np.asarray(offs), np.asarray(offs2))
-    np.testing.assert_array_equal(
-        np.asarray(extractor_forward(params, tiles_tf)),
-        np.asarray(extractor_forward(params, tiles_staged)))
+    a = np.asarray(extractor_forward(params, tiles_tf))
+    b = np.asarray(extractor_forward(params, tiles_staged))
+    np.testing.assert_array_equal(a > 0, b > 0)
+    np.testing.assert_allclose(a, b, rtol=0, atol=CROSS_PROGRAM_LOGIT_ATOL)
 
 
 def test_resize_matrix_matches_jax_image():
