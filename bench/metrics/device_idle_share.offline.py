@@ -1,0 +1,7 @@
+"""Idle share of the device in an offline cell (%): one minus the union
+of the device's operation intervals over the traced window, averaged
+over the chips.  Moves images_per_s."""
+
+
+def read(ctx):
+    return 100.0 * ctx.reduce.idle_share(ctx.trace)

@@ -1,0 +1,13 @@
+"""The benchmark's self-tests run on the CPU, by hand:
+
+    python -m pytest bench/tests -q
+
+They are not part of the repository's tier-1 suite (``pytest.ini``
+collects ``tests/`` only)."""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
