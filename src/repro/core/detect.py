@@ -95,7 +95,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Union
 import jax
 import numpy as np
 
-from repro.core import interleave, lanes as lanes_lib
+from repro.core import interleave, lanes as lanes_lib, spans
 # make_device_rs / STAGE_NAMES moved to repro.core.stages; re-exported
 # here for callers that import them from the pipeline module
 from repro.core.stages import (STAGE_NAMES, StageRegistry,  # noqa: F401
@@ -226,11 +226,12 @@ class DetectionPipeline:
         with self._stats_lock:
             self.stats["batches"] += 1
             self.stats["images"] += b
-        out = {"message_bits": np.asarray(msg), "ok": np.asarray(ok),
-               "n_corrected": np.asarray(ncorr),
-               "logits": np.asarray(logits)}
-        if tiles_used is not None and self.stages.policy.enabled:
-            out["tiles_used"] = np.asarray(tiles_used)
+        with spans.span("wait.device", n=b):
+            out = {"message_bits": np.asarray(msg), "ok": np.asarray(ok),
+                   "n_corrected": np.asarray(ncorr),
+                   "logits": np.asarray(logits)}
+            if tiles_used is not None and self.stages.policy.enabled:
+                out["tiles_used"] = np.asarray(tiles_used)
         if self.gt is not None:
             out["match"] = np.all(
                 out["message_bits"] == self.gt[None, : msg.shape[1]],
@@ -253,18 +254,25 @@ class DetectionPipeline:
         (bucket shaping) pass ``true_b`` so pad rows never escalate
         (they repeat the last real image and get sliced off anyway)."""
         b = raw_batch.shape[0]
+        item = None     # the batch's sequence number, for its spans
         if key is None:
+            item = self._seq
             key = self._batch_key(self._seq)
             self._seq += 1
         if self.stages.fused_keyed is not None:
-            keys = self.stages.image_keys(key, b)
-            (rs_out, logits) = self.stages.fused_keyed(raw_batch, keys)
+            # one program for the three stages: one span
+            with spans.span("stage.decode", item=item, n=b):
+                keys = self.stages.image_keys(key, b)
+                (rs_out, logits) = self.stages.fused_keyed(raw_batch, keys)
             msg, ok, ncorr = (rs_out["message_bits"], rs_out["ok"],
                               rs_out["n_corrected"])
         else:
-            x, keys = self._ingest(raw_batch, key)
-            logits = self._decode_x(x, keys)
-            msg, ok, ncorr = self._rs_correct(self._bits(logits))
+            with spans.span("stage.ingest", item=item, n=b):
+                x, keys = self._ingest(raw_batch, key)
+            with spans.span("stage.decode", item=item, n=b):
+                logits = self._decode_x(x, keys)
+            with spans.span("stage.rs", item=item, n=b):
+                msg, ok, ncorr = self._rs_correct(self._bits(logits))
         tiles_used = None
         if self.stages.policy.enabled:
             msg, ok, ncorr, logits, tiles_used = \
@@ -346,12 +354,15 @@ class DetectionPipeline:
                 for i, item in enumerate(batches):
                     raw, tb = (item if isinstance(item, tuple)
                                else (item, None))
-                    bkey = self._batch_key(seq0 + i)
-                    p = {"raw": raw, "seq": seq0 + i,
-                         "keys": self.stages.image_keys(
-                             bkey, raw.shape[0])}
-                    if tb is not None:
-                        p["true_b"] = tb
+                    # item i of the stream is the executor's sequence
+                    # number i: its stage and sink spans carry it too
+                    with spans.span("feed", item=i, n=raw.shape[0]):
+                        bkey = self._batch_key(seq0 + i)
+                        p = {"raw": raw, "seq": seq0 + i,
+                             "keys": self.stages.image_keys(
+                                 bkey, raw.shape[0])}
+                        if tb is not None:
+                            p["true_b"] = tb
                     yield p
 
             for r in ex.run(feed()):
@@ -368,9 +379,11 @@ class DetectionPipeline:
             for item in it:
                 raw, tb = (item if isinstance(item, tuple)
                            else (item, None))
+                seq = self._seq       # the item of detect_batch's spans
                 r = self.detect_batch(raw, true_b=tb)
                 if on_result is not None:
-                    on_result(len(results), r)
+                    with spans.span("sink", item=seq):
+                        on_result(len(results), r)
                 results.append(r)
                 n_img += raw.shape[0]
             lane_map = {n: 1 for n in STAGE_NAMES}

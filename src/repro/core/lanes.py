@@ -42,6 +42,8 @@ import threading
 from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, \
     Sequence
 
+from repro.core import spans
+
 
 @dataclasses.dataclass
 class Stage:
@@ -152,23 +154,49 @@ class LaneExecutor:
         self._service_threads: List[threading.Thread] = []
         self._lane_counts: Dict[str, int] = {}
 
-    # -- cooperative queue ops so close() can unstick blocked workers ----
+    # -- cooperative queue ops so close() can unstick blocked workers;
+    # the time they block is a "wait.queue" span ------------------------
     def _put(self, q: "queue.Queue", item) -> bool:
-        while not self._cancel.is_set():
-            try:
-                q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
+        if self._cancel.is_set():
+            return False
+        try:
+            q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with spans.span("wait.queue"):
+            while not self._cancel.is_set():
+                try:
+                    q.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     def _get(self, q: "queue.Queue"):
-        while not self._cancel.is_set():
-            try:
-                return q.get(timeout=0.05)
-            except queue.Empty:
-                continue
+        if self._cancel.is_set():
+            return _DONE
+        try:
+            return q.get_nowait()
+        except queue.Empty:
+            pass
+        with spans.span("wait.queue"):
+            while not self._cancel.is_set():
+                try:
+                    return q.get(timeout=0.05)
+                except queue.Empty:
+                    continue
         return _DONE
+
+    @staticmethod
+    def _call(stage: Stage, payload, item: int):
+        """``stage.fn(payload)`` as a ``stage.<name>`` span; an error
+        becomes the payload's :class:`_Failure`."""
+        with spans.span("stage." + stage.name, item=item):
+            try:
+                return stage.fn(payload)
+            except BaseException as e:
+                return _Failure(e)
 
     def close(self):
         """Cancel in-flight work (workers drain and exit).  In service
@@ -239,13 +267,10 @@ class LaneExecutor:
                 return
             if isinstance(got, _Retire):   # live lane removal
                 return
-            seq, payload = got
+            seq, item, payload = got
             if not isinstance(payload, _Failure):
-                try:
-                    payload = stage.fn(payload)
-                except BaseException as e:
-                    payload = _Failure(e)
-            self._put(nxt, (seq, payload))
+                payload = self._call(stage, payload, item)
+            self._put(nxt, (seq, item, payload))
 
     def _deliver_rejection(self, ticket: Ticket, callback):
         """Reject a ticket AND fire its callback: completion callbacks
@@ -265,7 +290,7 @@ class LaneExecutor:
             got = self._get(self._out_q)
             if got is _DONE:          # cancelled
                 return
-            seq, payload = got
+            seq, _item, payload = got
             with self._lock:
                 entry = self._tickets.pop(seq, None)
                 if not self._tickets:
@@ -284,15 +309,17 @@ class LaneExecutor:
                     pass              # callbacks must not kill the sink
 
     def submit(self, payload, *,
-               callback: Optional[Callable[[Ticket], None]] = None
-               ) -> Ticket:
+               callback: Optional[Callable[[Ticket], None]] = None,
+               item: Optional[int] = None) -> Ticket:
         """Enqueue one payload; returns its :class:`Ticket`.
 
         Blocks while the first stage queue is full — the executor's
         bounded queues are the backpressure surface (admission control
         with a hard depth bound lives in the caller, e.g. the
         micro-batcher).  ``callback(ticket)`` fires on the dispatcher
-        thread the moment the payload completes (out of order)."""
+        thread the moment the payload completes (out of order).
+        ``item`` is the id the payload's stage spans carry (default:
+        the ticket's sequence number)."""
         if not self._service:
             raise RuntimeError(f"{self.name}: submit() requires service "
                                "mode — call start() first")
@@ -303,7 +330,8 @@ class LaneExecutor:
             self._submit_seq += 1
             ticket = Ticket(seq)
             self._tickets[seq] = (ticket, callback)
-        if not self._put(self._qs[0], (seq, payload)):
+        if not self._put(self._qs[0],
+                         (seq, seq if item is None else item, payload)):
             with self._lock:
                 entry = self._tickets.pop(seq, None)
                 if not self._tickets:
@@ -411,13 +439,8 @@ class LaneExecutor:
                     self._put(nxt if last else in_q, _DONE)
                     return
                 seq, payload = got
-                if isinstance(payload, _Failure):
-                    self._put(nxt, (seq, payload))
-                    continue
-                try:
-                    payload = stage.fn(payload)
-                except BaseException as e:
-                    payload = _Failure(e)
+                if not isinstance(payload, _Failure):
+                    payload = self._call(stage, payload, seq)
                 self._put(nxt, (seq, payload))
 
         threads = [threading.Thread(target=feeder, daemon=True,
@@ -436,6 +459,8 @@ class LaneExecutor:
         # result (each lane finishes + forwards its in-flight item
         # before consuming the sentinel), so draining until _DONE then
         # flushing the buffer sees every sequence number exactly once.
+        # Each result's hand-over, the consumer's work on it included,
+        # is a "sink" span.
         buf: Dict[int, Any] = {}
         next_seq = 0
         done = False
@@ -449,11 +474,12 @@ class LaneExecutor:
                     seq, payload = got
                     buf[seq] = payload
                 while next_seq in buf:
-                    payload = buf.pop(next_seq)
-                    next_seq += 1
-                    if isinstance(payload, _Failure):
-                        raise payload.err
-                    yield payload
+                    with spans.span("sink", item=next_seq):
+                        payload = buf.pop(next_seq)
+                        next_seq += 1
+                        if isinstance(payload, _Failure):
+                            raise payload.err
+                        yield payload
                 if done and buf and next_seq not in buf:
                     raise RuntimeError(
                         f"{self.name}: lost sequence {next_seq} "

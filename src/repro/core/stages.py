@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import extractor as extractor_lib
-from repro.core import lanes as lanes_lib, tiling, transforms
+from repro.core import lanes as lanes_lib, spans, tiling, transforms
 from repro.core.extractor import extractor_forward
 from repro.core.rs.codec import RSCode, rs_decode
 from repro.core.rs import jax_rs
@@ -66,7 +66,8 @@ def make_device_rs(code: RSCode) -> Callable:
         from repro.kernels import ops as kops
 
         def decode(bits):
-            return kops.rs_decode(bits, code=code)
+            with jax.named_scope("rs"):
+                return kops.rs_decode(bits, code=code)
 
         # jitted so sharded inputs (run_batch) go through the SPMD
         # partitioner instead of eager multi-device dispatch
@@ -269,25 +270,28 @@ class StageRegistry:
         # ingest consumes the per-image fold_in keys as an input — the
         # derivation itself is image_keys(), shared by every caller.
         # Tile-first: offsets from the keys (static geometry only),
-        # then one kernel straight to the decode input.
+        # then one kernel straight to the decode input.  Each stage
+        # function names its ops with a scope of the stage's name.
         def ingest_keyed(raw, keys):
-            if self.tile_first:
-                from repro.kernels import ops as kops
-                offs = tiling.tile_first_offsets(
-                    cfg.strategy, keys, img_size=cfg.img_size,
-                    tile=cfg.tile)
-                return kops.fused_tile_preprocess(
-                    raw, offs, resize=cfg.resize_src, crop=cfg.img_size,
-                    tile=cfg.tile)
-            return preprocess(raw)
+            with jax.named_scope("ingest"):
+                if self.tile_first:
+                    from repro.kernels import ops as kops
+                    offs = tiling.tile_first_offsets(
+                        cfg.strategy, keys, img_size=cfg.img_size,
+                        tile=cfg.tile)
+                    return kops.fused_tile_preprocess(
+                        raw, offs, resize=cfg.resize_src,
+                        crop=cfg.img_size, tile=cfg.tile)
+                return preprocess(raw)
 
         def decode_keyed(x, keys):
-            if self.tile_first or cfg.mode == "sequential":
-                tiles = x  # tiles from ingest / full-image decode
-            else:
-                tiles, _ = tiling.select_tiles_per_image(
-                    cfg.strategy, keys, x, cfg.tile)
-            return extract(tiles)
+            with jax.named_scope("decode"):
+                if self.tile_first or cfg.mode == "sequential":
+                    tiles = x  # tiles from ingest / full-image decode
+                else:
+                    tiles, _ = tiling.select_tiles_per_image(
+                        cfg.strategy, keys, x, cfg.tile)
+                return extract(tiles)
 
         # embed-emitting decode: same tile selection, extractor returns
         # (logits, gap_embedding).  The logits ops are identical —
@@ -295,12 +299,13 @@ class StageRegistry:
         # round-0 decode whenever the near-duplicate cache is on
         # without perturbing the bit-identity contract.
         def decode_keyed_embed(x, keys):
-            if self.tile_first or cfg.mode == "sequential":
-                tiles = x
-            else:
-                tiles, _ = tiling.select_tiles_per_image(
-                    cfg.strategy, keys, x, cfg.tile)
-            return extract_embed(tiles)
+            with jax.named_scope("decode"):
+                if self.tile_first or cfg.mode == "sequential":
+                    tiles = x
+                else:
+                    tiles, _ = tiling.select_tiles_per_image(
+                        cfg.strategy, keys, x, cfg.tile)
+                return extract_embed(tiles)
 
         self.ingest_keyed = jax.jit(ingest_keyed)
         self.decode_keyed = jax.jit(decode_keyed)
@@ -447,7 +452,9 @@ class StageRegistry:
                                      else jnp.asarray(bits))
             return (rs_out["message_bits"], rs_out["ok"],
                     rs_out["n_corrected"])
-        return self._rs_host(np.asarray(bits))
+        with spans.span("wait.device", n=bits.shape[0]):
+            bits = np.asarray(bits)
+        return self._rs_host(bits)
 
     # -- adaptive multi-tile escalation --------------------------------
     def escalate_round(self, raw, keys, r: int):

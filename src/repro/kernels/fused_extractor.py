@@ -220,6 +220,7 @@ def fused_extractor(tiles, packed, *, interpret: bool = True,
         scratch_shapes=[pltpu.VMEM((L + 2 * pad, 3), jnp.float32),
                         pltpu.VMEM((2 * S, C), jnp.float32)],
         interpret=interpret,
+        name="fused_extractor",
     )(tiles, *leaves)
     out = tuple(o.reshape(b, n_bits) for o in out)
     return _add_corr(out if with_embed else out[0], packed, tiles,
@@ -350,6 +351,7 @@ def fused_extractor_blocked(tiles, packed, *, batch_block: int = 1,
         out_shape=[out_shape] * n_out if with_embed else out_shape,
         scratch_shapes=_scratch_shapes(bb, l, C),
         interpret=interpret,
+        name="fused_extractor_blocked",
         **kwargs,
     )(tiles, *leaves)
     return _add_corr(tuple(out) if with_embed else out, packed, tiles,

@@ -135,5 +135,6 @@ def fused_preprocess(raw, *, resize: int = 256, crop: int = 256,
                                lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 3, crop, crop), jnp.float32),
         interpret=interpret,
+        name="fused_preprocess",
     )(to_planar(raw), ry, rx)
     return from_planar(out)

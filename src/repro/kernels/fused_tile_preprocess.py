@@ -111,5 +111,6 @@ def fused_tile_preprocess(raw, offsets, *, resize: int = 256,
                                lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 3, tile, tile), jnp.float32),
         interpret=interpret,
+        name="fused_tile_preprocess",
     )(to_planar(raw), ry_t, rx_t)
     return from_planar(out)

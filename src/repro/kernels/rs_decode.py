@@ -230,6 +230,7 @@ def rs_decode_batch(bits, *, code: RSCode = DEFAULT_CODE,
             jax.ShapeDtypeStruct((2, Bp), jnp.int32),
         ],
         interpret=interpret,
+        name="rs_decode",
     )(bits_t)
 
     def unplanar(x):

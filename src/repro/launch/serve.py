@@ -31,7 +31,7 @@ from typing import Callable, Dict, Iterable, List, Optional, \
 import jax
 import numpy as np
 
-from repro.core import allocator, scheduler as sched_lib
+from repro.core import allocator, scheduler as sched_lib, spans
 from repro.core.detect import DetectionConfig, DetectionPipeline, \
     STAGE_NAMES
 from repro.data import pipeline as data_lib
@@ -113,21 +113,11 @@ class DetectionService:
         return self.allocation
 
     # -- Algorithm 2 + lane-executor streaming -----------------------------
-    def serve(self, batches: Iterable, *,
-              use_scheduler: bool = True,
-              on_result: Optional[Callable[[int, dict], None]] = None
-              ) -> ServiceReport:
-        """Run a stream of (possibly ragged) batches through the lane
-        executor.  With the scheduler on, each request batch is split
-        into LPT-placed mini-batch tasks first (Algorithm 2); the task
-        slices then flow through the executor as the work stream.
-
-        ``on_result(i, res)`` receives work item ``i``'s result, pad
-        rows sliced off, as it leaves the executor.  Work items are
-        contiguous slices of the batches, in order; item ``i`` ran
-        with the pipeline's batch key ``seq0 + i``, where ``seq0`` is
-        the pipeline's batch counter when ``serve`` was called."""
-        mon = sched_lib.StragglerMonitor()
+    def _plan(self, batches: Iterable, use_scheduler: bool):
+        """The work items of a serve call: each batch cut into
+        LPT-placed mini-batch slices (Algorithm 2) or kept whole, each
+        padded to its bucket; with the LPT per-lane predicted loads
+        summed over the batches (None without the scheduler)."""
         lane_loads: Optional[List[float]] = None
         work: List[Tuple[np.ndarray, int]] = []  # (padded slice, true b)
         for raw in batches:
@@ -157,6 +147,25 @@ class DetectionService:
                             work.append(pad_to_bucket(sl, self.pad_bucket))
             else:
                 work.append(pad_to_bucket(raw, self.pad_bucket))
+        return work, lane_loads
+
+    def serve(self, batches: Iterable, *,
+              use_scheduler: bool = True,
+              on_result: Optional[Callable[[int, dict], None]] = None
+              ) -> ServiceReport:
+        """Run a stream of (possibly ragged) batches through the lane
+        executor.  With the scheduler on, each request batch is split
+        into LPT-placed mini-batch tasks first (Algorithm 2); the task
+        slices then flow through the executor as the work stream.
+
+        ``on_result(i, res)`` receives work item ``i``'s result, pad
+        rows sliced off, as it leaves the executor.  Work items are
+        contiguous slices of the batches, in order; item ``i`` ran
+        with the pipeline's batch key ``seq0 + i``, where ``seq0`` is
+        the pipeline's batch counter when ``serve`` was called."""
+        mon = sched_lib.StragglerMonitor()
+        with spans.span("serve.plan"):
+            work, lane_loads = self._plan(batches, use_scheduler)
 
         def feed():
             for tid, (sl, tb) in enumerate(work):

@@ -52,6 +52,8 @@ from typing import Any, Dict, List, Mapping, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
+
 
 class AdmissionError(RuntimeError):
     """Request rejected at admission (invalid, or queue depth bound)."""
@@ -110,7 +112,8 @@ class MicroBatch:
     slots: List[Tuple[Any, int, int]]   # (slot, offset, n) per request
     true_b: int
     padded_b: int
-    t_formed: float
+    t_formed: float           # when its requests left the queue
+    t_enq: List[float]        # per request, aligned with slots
 
     @property
     def occupancy(self) -> float:
@@ -250,8 +253,8 @@ class MicroBatcher:
         return min(q[0].t_enq + self._wait_ms[c] / 1e3
                    for c, q in self._q.items() if q)
 
-    def next_batch(self, timeout: Optional[float] = None
-                   ) -> Optional[MicroBatch]:
+    def next_batch(self, timeout: Optional[float] = None,
+                   item: Optional[int] = None) -> Optional[MicroBatch]:
         """Block until a micro-batch is ready (or ``timeout``); returns
         None on timeout or when closed and empty.
 
@@ -262,7 +265,11 @@ class MicroBatcher:
         deadline has already expired are promoted ahead of everything
         (earliest expired deadline first), so sustained high-class
         traffic can delay a lower class only up to its deadline, never
-        starve it out of batches entirely."""
+        starve it out of batches entirely.
+
+        A request's wait ends when it leaves the queue (``t_formed``);
+        forming the batch from them (joining images and keys, padding)
+        follows, as a ``batcher.form`` span with id ``item``."""
         cfg = self.cfg
         with self._cv:
             if not self._cv.wait_for(
@@ -309,22 +316,25 @@ class MicroBatcher:
             self._depth -= total
             self._cv.notify_all()    # wake blocked submitters
         assert take, "next_batch woke with an un-poppable queue head"
-        raw = (take[0].images if len(take) == 1
-               else np.concatenate([e.images for e in take]))
-        keys = (take[0].keys if len(take) == 1
-                else jnp.concatenate([e.keys for e in take]))
-        raw, true_b = pad_to_bucket(raw, cfg.bucket)
-        pad = raw.shape[0] - true_b
-        if pad:
-            # pad keys like the images: repeated rows are inert (results
-            # sliced off before the scatter), any key value works
-            keys = jnp.concatenate([keys, jnp.repeat(keys[-1:], pad,
-                                                     axis=0)])
-        slots, off = [], 0
-        for e in take:
-            n = e.images.shape[0]
-            slots.append((e.slot, off, n))
-            off += n
-        return MicroBatch(raw=raw, keys=keys, slots=slots, true_b=true_b,
-                          padded_b=raw.shape[0],
-                          t_formed=time.perf_counter())
+        with spans.span("batcher.form", item=item, n=total):
+            raw = (take[0].images if len(take) == 1
+                   else np.concatenate([e.images for e in take]))
+            keys = (take[0].keys if len(take) == 1
+                    else jnp.concatenate([e.keys for e in take]))
+            raw, true_b = pad_to_bucket(raw, cfg.bucket)
+            pad = raw.shape[0] - true_b
+            if pad:
+                # pad keys like the images: repeated rows are inert
+                # (results sliced off before the scatter), any key
+                # value works
+                keys = jnp.concatenate([keys, jnp.repeat(keys[-1:], pad,
+                                                         axis=0)])
+            slots, off = [], 0
+            for e in take:
+                n = e.images.shape[0]
+                slots.append((e.slot, off, n))
+                off += n
+            return MicroBatch(raw=raw, keys=keys, slots=slots,
+                              true_b=true_b, padded_b=raw.shape[0],
+                              t_formed=now,
+                              t_enq=[e.t_enq for e in take])

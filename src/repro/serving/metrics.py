@@ -5,7 +5,8 @@ Deliberately dependency-free (no prometheus client in the container):
 a :class:`MetricsRegistry` is a thread-safe dict of counters/gauges
 plus bounded reservoirs for distributions.  ``snapshot()`` renders the
 report the server and the fig11/fig12 benchmarks consume — queue
-depth, batch occupancy, p50/p95/p99 request latency, throughput, and
+depth, batch occupancy, each request's wait in the batcher
+(``queue_wait_s``), p50/p95/p99 request latency, throughput, and
 the escalation telemetry (``images_escalated`` / ``escalation_batches``
 counters, the ``tiles_per_image`` distribution; the server derives
 ``escalation_rate`` from them in ``stats()``).
@@ -60,6 +61,7 @@ class MetricsRegistry:
         self._counters: Dict[str, float] = {}
         self._gauges: Dict[str, float] = {}
         self._dists: Dict[str, Deque[float]] = {}
+        self._seen: Dict[str, int] = {}    # observations, kept or not
         self._t0 = time.perf_counter()
 
     # -- primitives -----------------------------------------------------
@@ -77,6 +79,7 @@ class MetricsRegistry:
             if d is None:
                 d = self._dists[name] = deque(maxlen=_RESERVOIR)
             d.append(float(value))
+            self._seen[name] = self._seen.get(name, 0) + 1
 
     def counter(self, name: str) -> float:
         with self._lock:
@@ -86,16 +89,20 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """One dict with everything: counters, gauges, and per
         distribution n/mean/p50/p95/p99 (latencies in the unit they
-        were observed in — the server observes seconds)."""
+        were observed in — the server observes seconds) over the
+        newest observations the reservoir keeps, and ``dropped``, the
+        older ones it no longer holds."""
         with self._lock:
             wall = time.perf_counter() - self._t0
             out = {"wall_s": wall,
                    "counters": dict(self._counters),
                    "gauges": dict(self._gauges)}
             dists = {k: sorted(v) for k, v in self._dists.items()}
+            seen = dict(self._seen)
         for name, vals in dists.items():
             out[name] = {
                 "n": len(vals),
+                "dropped": seen[name] - len(vals),
                 "mean": (sum(vals) / len(vals)) if vals else float("nan"),
                 "p50": percentile(vals, 50),
                 "p95": percentile(vals, 95),
@@ -135,4 +142,5 @@ class MetricsRegistry:
             self._counters.clear()
             self._gauges.clear()
             self._dists.clear()
+            self._seen.clear()
             self._t0 = time.perf_counter()

@@ -79,7 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import allocator, lanes as lanes_lib
-from repro.core import scheduler as sched_lib
+from repro.core import scheduler as sched_lib, spans
 from repro.core.detect import DetectionConfig, DetectionPipeline
 from repro.core.stages import _pad_pow2
 from repro.serving import cache as cache_lib
@@ -515,6 +515,14 @@ class DetectionServer:
             rid = self._req_seq
             self._req_seq += 1
         n = images.shape[0]
+        with spans.span("submit", item=rid, n=n):
+            return self._admit(images, key, block, cls, rid)
+
+    def _admit(self, images: np.ndarray, key, block: bool, cls: str,
+               rid: int) -> RequestHandle:
+        """:meth:`submit` after the request has its class and id: the
+        cache lookup, the per-image keys and the batcher's admission."""
+        n = images.shape[0]
         handle = RequestHandle(rid, n, priority=cls)
         if self._exact is not None and n:
             digest = cache_lib.request_digest(images)
@@ -590,24 +598,50 @@ class DetectionServer:
         return {"raw": inf.mb.raw, "keys": inf.mb.keys}
 
     def _dispatch(self, inf: _InFlight, *, retry: bool = False):
-        if retry:
-            self.metrics.count("straggler_retries")
-        else:
-            with self._mon_lock:
-                self.mon.start(inf.tid)
-        self._ex.submit(self._payload(inf),
-                        callback=lambda t, inf=inf: self._on_done(inf, t))
+        n = (inf.mb.true_b if inf.esc is None else len(inf.esc.targets))
+        with spans.span("dispatch", item=inf.tid, n=n):
+            if retry:
+                self.metrics.count("straggler_retries")
+            else:
+                with self._mon_lock:
+                    self.mon.start(inf.tid)
+            self._ex.submit(
+                self._payload(inf), item=inf.tid,
+                callback=lambda t, inf=inf: self._on_done(inf, t))
+
+    def _next_tid(self) -> int:
+        with self._lock:
+            tid = self._tid_seq
+            self._tid_seq += 1
+        return tid
+
+    def _observe_waits(self, mb):
+        """Each request's wait in the batcher, from its enqueue until
+        it left the queue for this micro-batch (``mb.t_formed``; the
+        joins and padding after that are ``batcher.form``): one
+        ``queue_wait_s`` observation, and while spans record a
+        ``batcher.wait`` span on their clock."""
+        rec = spans.recording()
+        if rec:
+            ns, now = time.time_ns(), time.perf_counter()
+        for (slot, _, n), t_enq in zip(mb.slots, mb.t_enq):
+            self.metrics.observe("queue_wait_s", mb.t_formed - t_enq)
+            if rec:
+                spans.record("batcher.wait", ns - int((now - t_enq) * 1e9),
+                             ns - int((now - mb.t_formed) * 1e9),
+                             item=slot.rid, n=n)
 
     def _pump_loop(self):
+        tid = self._next_tid()      # the id of the next micro-batch
         while not self._stop.is_set():
-            mb = self.batcher.next_batch(timeout=0.05)
+            mb = self.batcher.next_batch(timeout=0.05, item=tid)
             if mb is None:
                 continue
+            inf = _InFlight(mb=mb, tid=tid)
             with self._lock:
-                tid = self._tid_seq
-                self._tid_seq += 1
-                inf = _InFlight(mb=mb, tid=tid)
                 self._inflight[tid] = inf
+            tid = self._next_tid()
+            self._observe_waits(mb)
             self.metrics.observe("batch_occupancy", mb.occupancy)
             self.metrics.observe("batch_images", mb.true_b)
             self.metrics.gauge("queue_depth", self.batcher.depth())
@@ -624,17 +658,23 @@ class DetectionServer:
 
     def _finish_payload(self, p: dict) -> dict:
         """Stage-graph sink: device -> numpy on the rs lane."""
-        out = {"message_bits": np.asarray(p["msg"]),
-               "ok": np.asarray(p["ok"]),
-               "n_corrected": np.asarray(p["ncorr"]),
-               "logits": np.asarray(p["logits"])}
-        if "embed" in p:         # round-0 GAP embeddings (tier-2 cache)
-            out["embed"] = np.asarray(p["embed"])
+        with spans.span("wait.device", n=p["logits"].shape[0]):
+            out = {"message_bits": np.asarray(p["msg"]),
+                   "ok": np.asarray(p["ok"]),
+                   "n_corrected": np.asarray(p["ncorr"]),
+                   "logits": np.asarray(p["logits"])}
+            if "embed" in p:     # round-0 GAP embeddings (tier-2 cache)
+                out["embed"] = np.asarray(p["embed"])
         return out
 
     def _on_done(self, inf: _InFlight, ticket):
         """Executor callback (completion order): scatter to requests,
         or advance the escalation state machine for round-r batches."""
+        n = (inf.mb.true_b if inf.esc is None else len(inf.esc.targets))
+        with spans.span("scatter", item=inf.tid, n=n):
+            self._scatter(inf, ticket)
+
+    def _scatter(self, inf: _InFlight, ticket):
         with self._lock:
             if inf.done:          # a speculative duplicate lost the race
                 return
@@ -658,8 +698,6 @@ class DetectionServer:
             return
         with self._esc_lock:
             self._scatter_round0(inf.mb, res)
-        self.metrics.observe("batch_latency_s",
-                             time.perf_counter() - inf.mb.t_formed)
 
     def _settle(self, slot, result: Dict[str, np.ndarray], *,
                 count_tiles: bool = True):
@@ -821,8 +859,6 @@ class DetectionServer:
         blocking submit there wedges the server — the dispatcher is
         the only consumer of the completion queue)."""
         self.metrics.count("escalation_batches")
-        self.metrics.observe("escalation_batch_images",
-                             len(group.targets))
         self._esc_q.put(group)
 
     def _esc_loop(self):
@@ -833,10 +869,9 @@ class DetectionServer:
                 group = self._esc_q.get(timeout=0.05)
             except queue.Empty:
                 continue
+            tid = self._next_tid()
+            inf = _InFlight(mb=None, tid=tid, esc=group)
             with self._lock:
-                tid = self._tid_seq
-                self._tid_seq += 1
-                inf = _InFlight(mb=None, tid=tid, esc=group)
                 self._inflight[tid] = inf
             try:
                 self._dispatch(inf)
@@ -901,6 +936,8 @@ class DetectionServer:
 
     # -- live reallocation -------------------------------------------
     def _timed(self, name: str, fn):
+        """``fn`` under the server's device pin, its round-0 wall time
+        folded into the EWMA that :meth:`stage_profiles` reads."""
         def timed_fn(p):
             t0 = time.perf_counter()
             with self._dev_ctx():
@@ -910,8 +947,7 @@ class DetectionServer:
                 # escalation rounds are tiny pow2 sub-batches: feeding
                 # them into the EWMA would skew the Algorithm-1 profiles
                 # (and _stage_b) toward a workload the allocator should
-                # not tune for — tracked separately instead
-                self.metrics.observe(f"stage_{name}_esc_s", dt)
+                # not tune for
                 return out
             with self._lock:
                 prev = self._stage_s.get(name)
@@ -921,7 +957,6 @@ class DetectionServer:
                     b = p["raw"].shape[0]
                     self._stage_b = (b if not self._stage_b
                                      else 0.8 * self._stage_b + 0.2 * b)
-            self.metrics.observe(f"stage_{name}_s", dt)
             return out
         return timed_fn
 
