@@ -66,8 +66,12 @@ discipline — nothing is restated here):
 Stage handoff is zero-copy: payloads stay device arrays between lanes
 (bits are thresholded on device, ``rs_mode="device"`` feeds them
 straight into the batched decoder — the Pallas Berlekamp-Welch kernel
-for the default (15,12) GF(16) code, ``jax_rs`` otherwise) and nothing
-is pulled to numpy before the sink (:meth:`_finish`).
+for the default (15,12) GF(16) code, ``jax_rs`` otherwise).  The RS
+lane starts every result's device-to-host copy as it dispatches, and
+``run_stream`` makes them numpy (:meth:`_finish`) as it takes each
+result from the executor in order, on the consumer's thread, so the
+RS lane never blocks on a result copy (the online server's sink stays
+on its RS lane).
 
 RNG discipline: batch k uses ``fold_in(key(seed), k)`` and image i of a
 batch uses ``fold_in(batch_key, i)``, so results are bit-identical
@@ -219,23 +223,28 @@ class DetectionPipeline:
 
     def _finish(self, msg, ok, ncorr, logits, b,
                 tiles_used=None) -> Dict[str, np.ndarray]:
-        """The sink: the single place device arrays become numpy.
-        ``tiles_used`` (escalation round counts) is reported only when
-        escalation is configured, so ``escalate_tiles=1`` results keep
-        the exact pre-escalation schema."""
-        with self._stats_lock:
-            self.stats["batches"] += 1
-            self.stats["images"] += b
-        with spans.span("wait.device", n=b):
-            out = {"message_bits": np.asarray(msg), "ok": np.asarray(ok),
-                   "n_corrected": np.asarray(ncorr),
-                   "logits": np.asarray(logits)}
+        """The single place device arrays become numpy, as a ``fetch``
+        span: in ``run_stream``'s in-order loop over the executor's
+        results, on the consumer's thread; directly in ``detect_batch``
+        and ``run_batch``.  One ``device_get`` starts every copy not
+        yet started before it blocks.  ``tiles_used`` (escalation round
+        counts) is reported only when escalation is configured, so
+        ``escalate_tiles=1`` results keep the exact pre-escalation
+        schema."""
+        with spans.span("fetch", n=b):
+            with self._stats_lock:
+                self.stats["batches"] += 1
+                self.stats["images"] += b
+            with spans.span("wait.device", n=b):
+                msg, ok, ncorr, logits = jax.device_get(
+                    (msg, ok, ncorr, logits))
+            out = {"message_bits": msg, "ok": ok, "n_corrected": ncorr,
+                   "logits": logits}
             if tiles_used is not None and self.stages.policy.enabled:
                 out["tiles_used"] = np.asarray(tiles_used)
-        if self.gt is not None:
-            out["match"] = np.all(
-                out["message_bits"] == self.gt[None, : msg.shape[1]],
-                axis=1)
+            if self.gt is not None:
+                out["match"] = np.all(
+                    msg == self.gt[None, : msg.shape[1]], axis=1)
         return out
 
     # ------------------------------------------------------------------
@@ -303,14 +312,15 @@ class DetectionPipeline:
     def build_stages(self, lanes: Optional[Dict[str, int]] = None
                      ) -> List[lanes_lib.Stage]:
         """The detection stage graph for the lane executor — the
-        registry's single payload-stage definition with :meth:`_finish`
-        as the sink (payloads carry pre-derived per-image ``keys``, so
-        stage functions are pure and any lane count is bit-identical to
-        serial; see :meth:`StageRegistry.build_stages`)."""
+        registry's single payload-stage definition (payloads carry
+        pre-derived per-image ``keys``, so stage functions are pure and
+        any lane count is bit-identical to serial; see
+        :meth:`StageRegistry.build_stages`).  Its results are payloads,
+        not numpy: pass each one the executor yields through
+        :meth:`_finish_payload`, as ``run_stream`` does."""
         ln = {**self.default_lanes(), **(lanes or {})}
         return self.stages.build_stages(
-            ln, finish=self._finish_payload,
-            depth=2 if self.cfg.interleave else 1)
+            ln, depth=2 if self.cfg.interleave else 1)
 
     # ------------------------------------------------------------------
     def run_stream(self, batches: Iterable, *, scheduled: bool = True,
@@ -366,6 +376,7 @@ class DetectionPipeline:
                     yield p
 
             for r in ex.run(feed()):
+                r = self._finish_payload(r)
                 if on_result is not None:
                     on_result(len(results), r)
                 results.append(r)

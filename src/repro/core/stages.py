@@ -48,6 +48,9 @@ from repro.core.rs.cpu_pool import RSCorrectionPool
 
 STAGE_NAMES = ("ingest", "decode", "rs")
 
+# the payload fields a stage graph's sink makes numpy
+_FETCHED = ("msg", "ok", "ncorr", "logits", "embed")
+
 # the code the Pallas Berlekamp-Welch kernel is specialised for
 _PALLAS_RS_CODE = (4, 15, 12)  # (m, n, k)
 
@@ -551,8 +554,14 @@ class StageRegistry:
         functions are pure and any lane count or arrival interleaving
         is bit-identical to serial) -> ``x`` -> ``logits`` ->
         ``msg``/``ok``/``ncorr``.  Between lanes everything stays a
-        device array (jitted stage fns return futures); ``finish(p)``
-        is the sink — the one place device arrays should become numpy.
+        device array (jitted stage fns return futures).  The rs stage
+        starts the device-to-host copy of every device output it
+        forwards (``copy_to_host_async``), so each item's copies run
+        together as soon as the device finishes it; whoever makes them
+        numpy later finds them in flight or done.  ``finish(p)``, when
+        given, does that on the rs lane (the server's sink).  Without
+        it the caller makes each result numpy as the executor yields
+        it, as ``run_stream`` does.
         Extra payload fields (request slots, timestamps) flow through
         untouched.
 
@@ -596,6 +605,9 @@ class StageRegistry:
         def st_rs(p):
             p["msg"], p["ok"], p["ncorr"] = self.rs_correct(
                 self.bits(p["logits"]))
+            for f in _FETCHED:
+                if isinstance(p.get(f), jax.Array):
+                    p[f].copy_to_host_async()
             if (escalate_inline and self.policy.enabled
                     and p.get("round", 0) == 0):
                 # payloads from padded feeders carry "true_b": only the

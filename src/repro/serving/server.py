@@ -657,8 +657,12 @@ class DetectionServer:
                                       error=e)
 
     def _finish_payload(self, p: dict) -> dict:
-        """Stage-graph sink: device -> numpy on the rs lane."""
-        with spans.span("wait.device", n=p["logits"].shape[0]):
+        """Stage-graph sink: device -> numpy on the rs lane, as a
+        ``fetch`` span (the scatter, the escalation re-submit and the
+        caches read numpy there).  The rs stage started every copy as
+        it dispatched, so they run together."""
+        n = p["logits"].shape[0]
+        with spans.span("fetch", n=n), spans.span("wait.device", n=n):
             out = {"message_bits": np.asarray(p["msg"]),
                    "ok": np.asarray(p["ok"]),
                    "n_corrected": np.asarray(p["ncorr"]),

@@ -101,6 +101,62 @@ def test_run_stream_interleaved(tiny_trained):
     assert res["throughput_ips"] > 0
 
 
+def test_fetch_runs_in_the_in_order_sink_not_on_the_rs_lane():
+    """Escalation off, device RS: run_stream makes each work item's
+    results numpy as it takes them from the executor in order.  Every
+    ``fetch`` span, and the ``wait.device`` inside it, is on the
+    consumer's thread under its ``sink`` span; no RS lane records
+    either.  A fetch that fails re-raises in order, after the items
+    before it were handed on."""
+    import threading
+
+    from repro.core import spans
+
+    params = init_extractor(jax.random.key(0),
+                            n_bits=DEFAULT_CODE.codeword_bits,
+                            channels=8, depth=2)
+    cfg = DetectionConfig(tile=16, img_size=32, resize_src=40,
+                          mode="qrmark", rs_mode="device")
+    rng = np.random.default_rng(2)
+    data = [rng.integers(0, 256, (4, 64, 64, 3), dtype=np.uint8)
+            for _ in range(5)]
+    lanes = {"ingest": 1, "decode": 3, "rs": 2}
+    pipe = DetectionPipeline(cfg, params)
+    spans.take()
+    spans.enable()
+    try:
+        pipe.run_stream(data, lanes=lanes)
+        got = spans.take()
+    finally:
+        spans.disable()
+        spans.take()
+    ids = {s.id: s for s in got}
+    fetch = [s for s in got if s.name == "fetch"]
+    waits = [s for s in got if s.name == "wait.device"]
+    assert sorted(s.item for s in fetch) == list(range(len(data)))
+    assert all(s.n == 4 for s in fetch)
+    assert len(waits) == len(fetch)
+    assert all(ids[s.parent].name == "fetch" for s in waits)
+    assert all(ids[s.parent].name == "sink" for s in fetch)
+    assert {s.thread for s in fetch + waits} == {threading.get_ident()}
+    rs_lanes = {s.thread for s in got if s.name == "stage.rs"}
+    assert rs_lanes and threading.get_ident() not in rs_lanes
+
+    finish, handed, calls = pipe._finish, [], []
+
+    def failing(*a, **k):
+        calls.append(1)
+        if len(calls) == 3:
+            raise ValueError("fetch failed")
+        return finish(*a, **k)
+
+    pipe._finish = failing
+    with pytest.raises(ValueError, match="fetch failed"):
+        pipe.run_stream(data, lanes=lanes,
+                        on_result=lambda i, r: handed.append(i))
+    assert handed == [0, 1]
+
+
 def test_verify_threshold_fpr():
     """The binomial threshold must reject random bits at ~the target FPR
     and accept near-perfect matches."""

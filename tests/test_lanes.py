@@ -173,6 +173,59 @@ def test_qrmark_lane_count_is_bit_identical(tiny_params, rs_mode):
         p4.close()
 
 
+_SERVICES: dict = {}
+
+
+@pytest.mark.parametrize("engine", ["run_stream", "serve"])
+@pytest.mark.parametrize("escalate_tiles", [1, 2])
+@pytest.mark.parametrize("rs_mode", ["device", "cpu_sync"])
+@pytest.mark.parametrize("lanes", [(1, 1, 1), (1, 3, 2)])
+def test_stream_engines_match_serial_detect_batch(
+        tiny_params, lanes, rs_mode, escalate_tiles, engine):
+    """run_stream and DetectionService.serve, which make each result
+    numpy as it leaves the executor in order, equal serial detect_batch
+    calls on the same work items bitwise.  Batches of 3 are padded to
+    4: pad rows stay inert and are sliced off.  One service per
+    (rs_mode, escalate_tiles) runs every case, so each program compiles
+    once; its pipeline's running sequence number keys each run."""
+    from repro.launch.serve import DetectionService
+
+    svc = _SERVICES.get((rs_mode, escalate_tiles))
+    if svc is None:
+        cfg = DetectionConfig(tile=16, img_size=32, resize_src=40,
+                              mode="qrmark", rs_mode=rs_mode,
+                              escalate_tiles=escalate_tiles)
+        svc = _SERVICES[(rs_mode, escalate_tiles)] = DetectionService(
+            cfg, tiny_params)
+    pipe = svc.pipe
+    data = _batches(n=4, b=3, seed=5)
+    padded = [pad_to_bucket(raw) for raw in data]
+    ln = dict(zip(("ingest", "decode", "rs"), lanes))
+    seq0 = pipe._seq
+    if engine == "serve":
+        svc.lanes = ln
+        got = {}
+        rep = svc.serve(data, use_scheduler=False,
+                        on_result=lambda i, r: got.__setitem__(i, r))
+        assert rep.lanes == ln
+        out = [got[i] for i in range(len(data))]
+    else:
+        res = pipe.run_stream(padded, lanes=ln)
+        assert res["lanes"] == ln
+        out = [{k: v[:tb] for k, v in r.items()}
+               for r, (_, tb) in zip(res["results"], padded)]
+    assert pipe._seq == seq0 + len(data)
+    ref = [{k: v[:tb] for k, v in pipe.detect_batch(
+                raw, key=pipe._batch_key(seq0 + i), true_b=tb).items()}
+           for i, (raw, tb) in enumerate(padded)]
+    fields = ("message_bits", "ok", "n_corrected", "logits") + \
+        (("tiles_used",) if escalate_tiles > 1 else ())
+    for r0, r1 in zip(ref, out):
+        for f in fields:
+            np.testing.assert_array_equal(r0[f], r1[f], err_msg=f)
+            assert isinstance(r1[f], np.ndarray), f
+
+
 def test_run_batch_ragged_padding_is_inert(tiny_params):
     """Per-image keys: a padded ragged batch must give every real image
     the same result as the unpadded single-device run."""

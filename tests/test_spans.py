@@ -164,13 +164,15 @@ def test_run_stream_is_bit_identical_with_spans_and_spans_share_items(
             np.testing.assert_array_equal(r0[f], r1[f])
     got = recorded()
     for name in ("feed", "stage.ingest", "stage.decode", "stage.rs",
-                 "sink", "wait.device"):
+                 "sink", "fetch", "wait.device"):
         assert sorted(s.item for s in got if s.name == name) == \
             [0, 1, 2, 3], name
     ids = _by_id(got)
     for s in got:
         if s.name == "wait.device":
-            assert ids[s.parent].name == "stage.rs"
+            assert ids[s.parent].name == "fetch"
+        if s.name == "fetch":
+            assert ids[s.parent].name == "sink" and s.n == 4
     threads = {s.name: s.thread for s in got}
     assert threads["feed"] != threads["sink"] != threads["stage.decode"]
 
